@@ -49,7 +49,10 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
-FORMAT_VERSION = 1
+# 2: packed z-bit buffers use the planar byte layout of
+# docs/WIRE_FORMATS.md; a version-1 checkpoint holds interleaved bytes
+# of the same shape that would unpack as wrong codes, so it is refused.
+FORMAT_VERSION = 2
 ARRAYS_NAME = "arrays.npz"
 MANIFEST_NAME = "manifest.json"
 STEP_PREFIX = "step_"

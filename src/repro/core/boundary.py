@@ -211,15 +211,12 @@ def decode_sum_mean(total, scale, *, bits: int, n: int,
                     backend: str = "auto"):
     """Int32 code sum over n workers + shared rowwise scale -> mean
     values: the DP gradient-wire receiver.  n must be static (the mesh
-    size).  Association mirrors `Q.dequantize` (2T - n*lv integer-exact,
-    trailing divisions) so both backends round identically."""
+    size)."""
     assert isinstance(n, int) and n >= 1, n
     backend = resolve_backend(backend, bits)
     if backend == "pallas":
         return K.dequant_sum_mean(total, scale, bits=bits, n=n)
-    lv = (1 << bits) - 1
-    ic = total.astype(jnp.float32) * 2.0 - float(n * lv)
-    return ((ic * scale) / lv) / n
+    return Q.dequantize_sum_mean(total, scale, bits, n)
 
 
 def encode_codes_with_scale(x, scale, *, bits: int, stochastic: bool = False,

@@ -197,6 +197,11 @@ def ef_psum_mean_bucket(v_grad, err, axis_name, bits: int, key,
     return mean, new_err
 
 
+def _segment(x, j, seg: int):
+    """Rows j*seg ... (j+1)*seg of a (n*seg, .) array, j traced."""
+    return jax.lax.dynamic_slice_in_dim(x, j * seg, seg, 0)
+
+
 def _reduce_scatter_codes(packed, codes, n, ax, axis_name, bits,
                           backend):
     """The ring's reduce-scatter half, shared by the full ring and the
@@ -207,8 +212,7 @@ def _reduce_scatter_codes(packed, codes, n, ax, axis_name, bits,
     OWN segment, the segment row count, and the rank's flat ring index.
     Padded rows of a ragged last segment carry zero payload, so they
     accumulate zero sums."""
-    rows, d = codes.shape
-    pw = packed.shape[-1]
+    rows = codes.shape[0]
     seg = ring_segment_rows(rows, n)
     pad = seg * n - rows
     if pad:
@@ -217,15 +221,16 @@ def _reduce_scatter_codes(packed, codes, n, ax, axis_name, bits,
         # a zero scale (sharded wire) before touching the optimizer
         packed = jnp.pad(packed, ((0, pad), (0, 0)))
         codes = jnp.pad(codes, ((0, pad), (0, 0)))
-    psegs = packed.reshape(n, seg, pw)
-    csegs = codes.reshape(n, seg, d)
     i = _flat_axis_index(axis_name)
 
-    acc = jax.lax.dynamic_index_in_dim(csegs, i, 0, keepdims=False)
+    # segments are row slices of the 2-D arrays, never a (n, seg, .)
+    # reshape: on a TPU that reshape is a relayout whenever seg is not a
+    # multiple of the row tile, and it compiles in time linear in the
+    # rows (minutes for a 1.5B-parameter bucket)
+    acc = _segment(codes, i, seg)
     for t in range(1, n):
         perm = [(src, (src + t) % n) for src in range(n)]
-        send = jax.lax.dynamic_index_in_dim(psegs, (i + t) % n, 0,
-                                            keepdims=False)
+        send = _segment(packed, (i + t) % n, seg)
         recv = jax.lax.ppermute(send, ax, perm)
         acc = B.accumulate_codes(recv, acc, bits=bits, backend=backend)
     return acc, seg, i
@@ -409,8 +414,7 @@ def ring_ef_reduce_scatter_bucket(v_grad, err, axis_name, bits: int, key,
     rows = v.shape[0]
     pad = seg * n - rows
     s_pad = jnp.pad(s, ((0, pad), (0, 0))) if pad else s
-    s_own = jax.lax.dynamic_index_in_dim(
-        s_pad.reshape(n, seg, 1), i, 0, keepdims=False)
+    s_own = _segment(s_pad, i, seg)
     seg_mean = B.decode_sum_mean(acc, s_own, bits=bits, n=n,
                                  backend=backend)
     return seg_mean, new_err
@@ -472,15 +476,16 @@ def ring_ef_reduce_mean_bucket(v_grad, err, axis_name, bits: int, key,
 
     # ---- all-gather: rotate the packed segment sums to everyone --------
     own = B.pack_sums(acc, bits=bits, n=n, backend=backend)
-    gathered = jnp.zeros((n,) + own.shape, jnp.uint8)
-    gathered = jax.lax.dynamic_update_index_in_dim(gathered, own, i, 0)
+    gathered = jnp.zeros((n * seg, own.shape[-1]), jnp.uint8)
+    gathered = jax.lax.dynamic_update_slice_in_dim(gathered, own, i * seg,
+                                                   0)
     for t in range(1, n):
         perm = [(src, (src + t) % n) for src in range(n)]
         recv = jax.lax.ppermute(own, ax, perm)
-        gathered = jax.lax.dynamic_update_index_in_dim(
-            gathered, recv, (i - t) % n, 0)
+        gathered = jax.lax.dynamic_update_slice_in_dim(
+            gathered, recv, ((i - t) % n) * seg, 0)
 
-    total_p = gathered.reshape(n * seg, -1)[:rows]
+    total_p = gathered[:rows]
     total = B.unpack_sums(total_p, bits=bits, n=n, d=d, backend=backend)
     mean = B.decode_sum_mean(total, s, bits=bits, n=n, backend=backend)
     return mean, new_err

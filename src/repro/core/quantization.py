@@ -85,20 +85,31 @@ def quantize(
     return codes.astype(jnp.uint8), scale
 
 
+def dequantize_sum_mean(total: jax.Array, scale: jax.Array, bits: int,
+                        n: int) -> jax.Array:
+    """Mean of n dequantized code tensors, given their exact int32 sum
+    under one shared scale: mean_i ((2 c_i - lv) s) / lv
+    = ((2 T - n lv) s) / (n lv).  n = 1 is `dequantize`.
+
+    Plain jnp, so the Pallas kernels call it inside their bodies too:
+    this is the one place both boundary backends take the formula from."""
+    # ((2T - n*lv) * scale) * f32(1/(n*lv)), in this exact association:
+    # 2T - n*lv is integer-exact in f32, and the rest is two
+    # multiplications, which every backend rounds the same way (IEEE).
+    # A division by the constant n*lv is NOT: on a TPU, XLA and Mosaic
+    # round x / 255 differently.  The bit-identical reference/pallas
+    # boundary backend contract depends on this shape; don't "simplify"
+    # it to (c * (2/levels) - 1) * scale.
+    levels = n * ((1 << bits) - 1)
+    ic = total.astype(jnp.float32) * 2.0 - float(levels)
+    return (ic * scale) * (1.0 / levels)
+
+
 def dequantize(codes: jax.Array, scale: jax.Array, bits: int,
                dtype: jnp.dtype = jnp.float32) -> jax.Array:
     """Map b-bit codes back to values: the center of each grid cell,
     scaled — the inverse the whole parity contract rounds through."""
-    # ((2c - levels) * scale) / levels, in this exact association: 2c -
-    # levels is integer-exact in f32 (immune to FMA contraction), and the
-    # trailing division cannot contract with a downstream add — so every
-    # compilation of this chain (XLA CPU, fused Pallas kernel, eager)
-    # rounds identically.  The bit-identical reference/pallas boundary
-    # backend contract depends on this shape; don't "simplify" it to
-    # (c * (2/levels) - 1) * scale.
-    levels = (1 << bits) - 1
-    ic = codes.astype(jnp.float32) * 2.0 - float(levels)
-    return ((ic * scale) / levels).astype(dtype)
+    return dequantize_sum_mean(codes, scale, bits, 1).astype(dtype)
 
 
 def qdq(
@@ -137,7 +148,10 @@ def packed_width(n: int, bits: int) -> int:
 
 
 def pack_codes(codes: jax.Array, bits: int) -> jax.Array:
-    """Pack uint8 codes (< 2**bits) densely along the last axis."""
+    """Pack uint8 codes (< 2**bits) densely along the last axis, planar:
+    a row of n codes is zero-padded to k*pw (k = 8/bits, pw =
+    ceil(n/k)), and byte j holds codes j, j+pw, ..., j+(k-1)*pw at bit
+    shifts 0, bits, ..., (k-1)*bits (docs/WIRE_FORMATS.md)."""
     k = codes_per_byte(bits)
     if k == 1:
         return codes
@@ -145,9 +159,9 @@ def pack_codes(codes: jax.Array, bits: int) -> jax.Array:
     pad = (-n) % k
     if pad:
         codes = jnp.pad(codes, [(0, 0)] * (codes.ndim - 1) + [(0, pad)])
-    grouped = codes.reshape(*codes.shape[:-1], -1, k).astype(jnp.uint32)
-    shifts = (jnp.arange(k, dtype=jnp.uint32) * bits)
-    packed = jnp.sum(grouped << shifts, axis=-1)
+    planes = codes.reshape(*codes.shape[:-1], k, -1).astype(jnp.uint32)
+    shifts = (jnp.arange(k, dtype=jnp.uint32) * bits)[:, None]
+    packed = jnp.sum(planes << shifts, axis=-2)
     return packed.astype(jnp.uint8)
 
 
@@ -156,9 +170,9 @@ def unpack_codes(packed: jax.Array, bits: int, n: int) -> jax.Array:
     k = codes_per_byte(bits)
     if k == 1:
         return packed[..., :n]
-    shifts = (jnp.arange(k, dtype=jnp.uint32) * bits)
+    shifts = (jnp.arange(k, dtype=jnp.uint32) * bits)[:, None]
     mask = jnp.uint32((1 << bits) - 1)
-    vals = (packed[..., None].astype(jnp.uint32) >> shifts) & mask
+    vals = (packed[..., None, :].astype(jnp.uint32) >> shifts) & mask
     flat = vals.reshape(*packed.shape[:-1], -1)
     return flat[..., :n].astype(jnp.uint8)
 
@@ -207,15 +221,17 @@ def sum_packed_width(d: int, bits: int, n: int) -> int:
 
 def pack_sums(total: jax.Array, bits: int, n: int) -> jax.Array:
     """int32 code sums over n workers -> dense u8 payload
-    (`sum_wire_bits(bits, n)` bits per sum along the last axis)."""
+    (`sum_wire_bits(bits, n)` bits per sum along the last axis).  Widths
+    above 8 bits are byte-planar: plane b of a row (bytes b*d ...
+    (b+1)*d) holds the b-th little-endian byte of every sum."""
     sw = sum_wire_bits(bits, n)
     if sw <= 8:
         # sums < 2**sw <= 256 by construction: the code packer applies
         return pack_codes(total.astype(jnp.uint8), sw)
     nb = sw // 8
     t = total.astype(jnp.uint32)
-    shifts = jnp.arange(nb, dtype=jnp.uint32) * 8
-    b = (t[..., None] >> shifts) & jnp.uint32(0xFF)
+    shifts = (jnp.arange(nb, dtype=jnp.uint32) * 8)[:, None]
+    b = (t[..., None, :] >> shifts) & jnp.uint32(0xFF)
     return b.reshape(*t.shape[:-1], -1).astype(jnp.uint8)
 
 
@@ -225,9 +241,9 @@ def unpack_sums(packed: jax.Array, bits: int, n: int, d: int) -> jax.Array:
     if sw <= 8:
         return unpack_codes(packed, sw, d).astype(jnp.int32)
     nb = sw // 8
-    shifts = jnp.arange(nb, dtype=jnp.uint32) * 8
-    b = packed.astype(jnp.uint32).reshape(*packed.shape[:-1], -1, nb)
-    vals = jnp.sum(b << shifts, axis=-1)
+    shifts = (jnp.arange(nb, dtype=jnp.uint32) * 8)[:, None]
+    b = packed.astype(jnp.uint32).reshape(*packed.shape[:-1], nb, -1)
+    vals = jnp.sum(b << shifts, axis=-2)
     return vals[..., :d].astype(jnp.int32)
 
 

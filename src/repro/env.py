@@ -1,4 +1,5 @@
-"""Central accessors for every ``REPRO_*`` environment knob.
+"""Central accessors for every ``REPRO_*`` environment knob, and for the
+facts about the process the code observes instead of asking for.
 
 This module is the ONE place the codebase reads its environment
 switches.  Nothing outside it may call ``os.environ.get("REPRO_...")``
@@ -13,10 +14,6 @@ Knob table
 ========================  =======  ========================================
 knob                      default  meaning
 ========================  =======  ========================================
-REPRO_PALLAS_INTERPRET    ``1``    ``1`` runs every Pallas kernel in
-                                   interpret mode (CPU containers); ``0``
-                                   compiles via Mosaic on real TPUs.  Read
-                                   once at import of `repro.kernels.ops`.
 REPRO_BOUNDARY_BACKEND    unset    Overrides ``backend="auto"`` resolution
                                    for every boundary op
                                    (`core.boundary.resolve_backend`):
@@ -28,9 +25,13 @@ REPRO_ONCORE_PRNG         ``0``    ``1`` opts the Pallas encode kernels
                                    to the statistical gate).
 ========================  =======  ========================================
 
-Accessors read ``os.environ`` at call time (except the interpret flag,
-which `repro.kernels.ops` snapshots once at import, before any kernel
-is built), so tests may ``monkeypatch.setenv`` freely.
+Accessors read ``os.environ`` at call time, so tests may
+``monkeypatch.setenv`` freely.
+
+Observed, not configured: whether Pallas kernels run in interpret mode
+(`pallas_interpret` — exactly when the default backend is not a TPU),
+and where JAX keeps its persistent compilation cache
+(`use_compile_cache`).
 """
 from __future__ import annotations
 
@@ -39,13 +40,17 @@ import os
 # name -> (default, one-line doc).  The keys are the exported knob set
 # tools/check_docs.py cross-checks against the README reference table.
 KNOBS = {
-    "REPRO_PALLAS_INTERPRET": (
-        "1", "Pallas interpret mode (1, default) vs Mosaic compile (0)"),
     "REPRO_BOUNDARY_BACKEND": (
         "", "force the boundary codec backend: reference | pallas"),
     "REPRO_ONCORE_PRNG": (
         "0", "1 = on-core TPU PRNG stochastic rounding (statistical gate)"),
 }
+
+# <checkout>/.jax_cache — fixed, so a later run in the same checkout
+# finds what an earlier one compiled (the path is part of the cache key)
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
 def _get(name: str) -> str:
@@ -53,12 +58,25 @@ def _get(name: str) -> str:
 
 
 def pallas_interpret() -> bool:
-    """Whether Pallas kernels should run in interpret mode (CPU default).
+    """Whether Pallas kernels run in interpret mode: exactly when the
+    default backend is not a TPU.  Asked at the first kernel call, never
+    at import, so importing the kernels initializes no backend."""
+    import jax
+    return jax.default_backend() != "tpu"
 
-    `repro.kernels.ops` snapshots this ONCE at import as its
-    ``INTERPRET`` constant — the single switch point for every fused
-    op."""
-    return _get("REPRO_PALLAS_INTERPRET") != "0"
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; call before the first
+    compile.  If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads
+    it and nothing else is set here; otherwise the cache goes to the
+    fixed in-checkout :data:`DEFAULT_COMPILE_CACHE`.  Returns the
+    directory in use."""
+    import jax
+    outside = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if outside:
+        return outside
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE)
+    return DEFAULT_COMPILE_CACHE
 
 
 def boundary_backend_override() -> str:

@@ -3,6 +3,7 @@
 `quant_pack` holds the boundary-codec kernels (one HBM pass per wire
 side), `ref` the bit-identical pure-jnp oracles, `ops` the
 ragged-row-padding wrappers callers use, and `flash_attention` the
-attention kernel family.  `REPRO_PALLAS_INTERPRET=1` (default) runs
-everything in interpret mode on CPU containers.
+attention kernel family.  The kernels compile through Mosaic on a TPU
+and run in interpret mode on every other backend
+(`repro.env.pallas_interpret`).
 """

@@ -17,18 +17,22 @@ materialized head repetition).  Supports causal masking, sliding window,
 and gemma-style logit softcap.  Backward remains the JAX-level flash
 custom_vjp (models/layers.py); a dedicated bwd kernel is future work.
 
-Validated in interpret mode against ref.flash_attention_ref; on real
-TPUs pass interpret=False.
+Validated in interpret mode against ref.flash_attention_ref; like the
+codec kernels it compiles through Mosaic on a TPU and runs in interpret
+mode elsewhere (`repro.env.pallas_interpret`).
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro import env
 
 NEG_INF = -1.0e9
 
@@ -81,7 +85,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_fwd(q, k, v, *, causal: bool = True,
                         window: int = 10 ** 9, softcap: float = 0.0,
                         block_q: int = 256, block_k: int = 256,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """q: (B, H, Sq, hd); k, v: (B, Hk, Sk, hd) with H % Hk == 0.
     Returns o: (B, H, Sq, hd)."""
     b, h, sq, hd = q.shape
@@ -118,6 +122,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=env.pallas_interpret() if interpret is None
+        else interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, sq, hd)

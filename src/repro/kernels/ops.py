@@ -1,9 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (the TPU
-mosaic pipeline is the target); set REPRO_PALLAS_INTERPRET=0 on real
-hardware — this flag is the single switch point for every fused op
-(snapshotted once at import via `repro.env.pallas_interpret`).
+The kernels compile through Mosaic on a TPU and run in interpret mode
+on any other backend (`repro.env.pallas_interpret`, asked at the first
+kernel call — importing this module initializes no backend).
 
 The wrappers flatten leading dims to the kernel's (rows, d) layout and
 zero-pad ragged row counts up to a block multiple (padding rows are
@@ -16,29 +15,29 @@ import functools
 
 import jax.numpy as jnp
 
-from repro import env
 from repro.kernels import quant_pack as _qp
 from repro.kernels import flash_attention as _fa
-
-INTERPRET = env.pallas_interpret()
 
 
 @functools.lru_cache(maxsize=1)
 def oncore_prng_supported() -> bool:
     """Whether the opt-in on-core PRNG encode path can lower here.
 
-    pltpu.prng_seed has no CPU interpret-mode lowering (jax 0.4.x), so
-    on CPU containers this is False and the boundary layer refuses the
-    REPRO_ONCORE_PRNG opt-in with a clear error instead of a lowering
-    crash."""
+    Interpret mode has no lowering for pltpu.prng_seed, so off a TPU
+    this is False and the boundary layer refuses the REPRO_ONCORE_PRNG
+    opt-in with a clear error instead of a lowering crash.  Only that
+    error is caught: on a TPU a Mosaic failure raises as one."""
     try:
-        x = jnp.zeros((8, 16), jnp.float32)
+        x = jnp.zeros((8, 128), jnp.float32)
         _qp.quantize_codes_scaled(
             x, jnp.ones((8, 1), jnp.float32),
-            bits=8, seed=jnp.zeros((2,), jnp.int32),
-            interpret=INTERPRET).block_until_ready()
+            bits=8, seed=jnp.zeros((2,), jnp.int32)).block_until_ready()
         return True
-    except Exception:
+    except NotImplementedError as e:
+        # interpret mode: "MLIR translation rule for primitive
+        # 'prng_seed' not found for platform cpu"
+        if "prng_seed" not in str(e):
+            raise
         return False
 
 
@@ -71,8 +70,7 @@ def boundary_compress(a, m, u=None, *, bits: int, seed=None,
     m2, _ = _as_rows(m, d, block_r)
     u2 = None if u is None else _as_rows(u, d, block_r)[0]
     packed, scale, m_new = _qp.delta_quantize_pack(
-        a2, m2, u2, bits=bits, seed=seed, block_r=block_r,
-        interpret=INTERPRET)
+        a2, m2, u2, bits=bits, seed=seed, block_r=block_r)
     return (packed[:r].reshape(*shape[:-1], -1),
             scale[:r].reshape(*shape[:-1], 1),
             m_new[:r].reshape(shape))
@@ -87,7 +85,7 @@ def boundary_decompress(packed, scale, m, *, bits: int,
     s2, _ = _as_rows(scale, 1, block_r)
     m2, _ = _as_rows(m, d, block_r)
     out = _qp.dequant_unpack_accumulate(
-        p2, s2, m2, bits=bits, block_r=block_r, interpret=INTERPRET)
+        p2, s2, m2, bits=bits, block_r=block_r)
     return out[:r].reshape(shape)
 
 
@@ -100,7 +98,7 @@ def quantize_pack(x, u=None, *, bits: int, seed=None, block_r: int = 128):
     x2, r = _as_rows(x, d, block_r)
     u2 = None if u is None else _as_rows(u, d, block_r)[0]
     packed, scale = _qp.quantize_pack(x2, u2, bits=bits, seed=seed,
-                                      block_r=block_r, interpret=INTERPRET)
+                                      block_r=block_r)
     return (packed[:r].reshape(*shape[:-1], -1),
             scale[:r].reshape(*shape[:-1], 1))
 
@@ -113,7 +111,7 @@ def unpack_dequant(packed, scale, *, bits: int, out_dtype=jnp.float32,
     p2, r = _as_rows(packed, pw, block_r)
     s2, _ = _as_rows(scale, 1, block_r)
     out = _qp.unpack_dequant(p2, s2, bits=bits, out_dtype=out_dtype,
-                             block_r=block_r, interpret=INTERPRET)
+                             block_r=block_r)
     return out[:r].reshape(*shape[:-1], out.shape[-1])
 
 
@@ -127,7 +125,7 @@ def quantize_pack_scaled(x, s, u=None, *, bits: int, block_r: int = 128):
     s2, _ = _as_rows(s, 1, block_r)
     u2 = None if u is None else _as_rows(u, d, block_r)[0]
     packed = _qp.quantize_pack_scaled(x2, s2, u2, bits=bits,
-                                      block_r=block_r, interpret=INTERPRET)
+                                      block_r=block_r)
     return packed[:r].reshape(*shape[:-1], -1)
 
 
@@ -136,8 +134,7 @@ def unpack_codes(packed, *, bits: int, block_r: int = 128):
     code-domain form the gradient wire accumulates with ``psum``."""
     shape = packed.shape
     p2, r = _as_rows(packed, shape[-1], block_r)
-    out = _qp.unpack_codes(p2, bits=bits, block_r=block_r,
-                           interpret=INTERPRET)
+    out = _qp.unpack_codes(p2, bits=bits, block_r=block_r)
     return out[:r].reshape(*shape[:-1], out.shape[-1])
 
 
@@ -154,8 +151,7 @@ def quantize_codes_scaled(x, s, u=None, *, bits: int, pack: bool = False,
     s2, _ = _as_rows(s, 1, block_r)
     u2 = None if u is None else _as_rows(u, d, block_r)[0]
     out = _qp.quantize_codes_scaled(x2, s2, u2, bits=bits, pack=pack,
-                                    seed=seed, block_r=block_r,
-                                    interpret=INTERPRET)
+                                    seed=seed, block_r=block_r)
     if pack:
         packed, codes = out
         return (packed[:r].reshape(*shape[:-1], -1),
@@ -171,8 +167,7 @@ def unpack_accumulate(packed, acc, *, bits: int, block_r: int = 128):
     shape = acc.shape
     p2, r = _as_rows(packed, packed.shape[-1], block_r)
     a2, _ = _as_rows(acc, acc.shape[-1], block_r)
-    out = _qp.unpack_accumulate(p2, a2, bits=bits, block_r=block_r,
-                                interpret=INTERPRET)
+    out = _qp.unpack_accumulate(p2, a2, bits=bits, block_r=block_r)
     return out[:r].reshape(shape)
 
 
@@ -181,8 +176,7 @@ def pack_sums(total, *, bits: int, n: int, block_r: int = 128):
     ring's all-gather payload (`Q.sum_wire_bits(bits, n)` bits/sum)."""
     shape = total.shape
     t2, r = _as_rows(total, shape[-1], block_r)
-    out = _qp.pack_sums(t2, bits=bits, n=n, block_r=block_r,
-                        interpret=INTERPRET)
+    out = _qp.pack_sums(t2, bits=bits, n=n, block_r=block_r)
     return out[:r].reshape(*shape[:-1], out.shape[-1])
 
 
@@ -190,8 +184,7 @@ def unpack_sums(packed, *, bits: int, n: int, block_r: int = 128):
     """Inverse of `pack_sums` for any (..., pw) payload."""
     shape = packed.shape
     p2, r = _as_rows(packed, shape[-1], block_r)
-    out = _qp.unpack_sums(p2, bits=bits, n=n, block_r=block_r,
-                          interpret=INTERPRET)
+    out = _qp.unpack_sums(p2, bits=bits, n=n, block_r=block_r)
     return out[:r].reshape(*shape[:-1], out.shape[-1])
 
 
@@ -203,12 +196,10 @@ def dequant_sum_mean(total, s, *, bits: int, n: int, block_r: int = 128):
     d = shape[-1]
     t2, r = _as_rows(total, d, block_r)
     s2, _ = _as_rows(s, 1, block_r)
-    out = _qp.dequant_sum_mean(t2, s2, bits=bits, n=n, block_r=block_r,
-                               interpret=INTERPRET)
+    out = _qp.dequant_sum_mean(t2, s2, bits=bits, n=n, block_r=block_r)
     return out[:r].reshape(shape)
 
 
 def flash_attention(q, k, v, **kw):
     """(B, H, Sq, hd) x (B, Hk, Sk, hd) -> (B, H, Sq, hd)."""
-    kw.setdefault("interpret", INTERPRET)
     return _fa.flash_attention_fwd(q, k, v, **kw)

@@ -60,36 +60,54 @@ and is TPU-only: interpret mode has no CPU lowering for ``prng_seed``
 
 TPU mapping: rows (tokens) are tiled along the grid; each grid step holds
 a (BLOCK_R, d) tile in VMEM — d (the model dim, ≤ 8 KiB per row in bf16)
-stays whole so the rowwise absmax is a single in-VMEM reduction, and the
-lane dimension stays 128-aligned for the VPU.  Packing uses u32 shifts on
-the (BLOCK_R, d/k, k) view.
+stays whole so the rowwise absmax is a single in-VMEM reduction.  Codes
+are int32 inside the kernels (Mosaic casts f32 <-> i32, not f32 <-> u32,
+and reduces no unsigned integers).  The wire layout is planar
+(docs/WIRE_FORMATS.md): byte j of a row holds codes j, j+pw, ...,
+j+(k-1)*pw, so packing is k shift-ors of contiguous lane blocks and
+unpacking is k shift-masks concatenated along the lanes — no lane split
+into (d/k, k) and no (rows, pw, k) intermediate.
 
-Kernels are validated against ref.py in interpret mode (CPU container);
-on real TPUs drop interpret=True — `repro.kernels.ops.INTERPRET`
-(REPRO_PALLAS_INTERPRET=0) is the single switch point.
+Kernels run in interpret mode exactly when the default backend is not
+a TPU (`repro.env.pallas_interpret`, resolved at the first call); on a
+TPU they compile through Mosaic, and a kernel Mosaic refuses raises.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import env
+from repro.core import quantization as Q
+
 _EPS = 1e-12
 DEFAULT_BLOCK_R = 128
+_GOLDEN = 0x9E3779B1 - (1 << 32)   # 2**32 / golden ratio, as an i32
+
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    """An explicit choice wins; None follows the platform."""
+    return env.pallas_interpret() if interpret is None else interpret
 
 
 def _oncore_uniform(shape, seed_ref):
     """Uniform(0,1) drawn from the on-core PRNG (TPU only).
 
-    Seeds with the two key words plus the grid position, so every block
-    gets an independent stream; 24 mantissa bits of each u32 give an
-    exact-in-f32 uniform on {0, ..., 2**24-1} / 2**24."""
-    pltpu.prng_seed(seed_ref[0], seed_ref[1], pl.program_id(0))
-    rb = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
-    return (rb >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+    Seeds with the two key words, the first xor-ed with the grid
+    position times an odd constant (Mosaic takes at most two seed
+    words), so every block gets its own stream and block i of one key
+    does not replay block i-1 of the next; the top 24 bits of each
+    32-bit draw give an exact-in-f32 uniform on {0, ..., 2**24-1} / 2**24."""
+    block = pl.program_id(0) * jnp.int32(_GOLDEN)
+    pltpu.prng_seed(seed_ref[0] ^ block, seed_ref[1])
+    rb = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.int32)
+    top = jax.lax.shift_right_logical(rb, jnp.int32(8))
+    return top.astype(jnp.float32) * (1.0 / (1 << 24))
 
 
 def _seed_spec():
@@ -124,7 +142,7 @@ def _levels(bits: int) -> int:
 
 
 def _quant_codes(x, scale, bits: int, u=None):
-    """f32 values + rowwise scale -> u32 codes on the uniform grid.
+    """f32 values + rowwise scale -> i32 codes on the uniform grid.
 
     u: uniform(0,1) noise of x.shape for stochastic rounding (the same
     comparison `u < frac` as jax.random.bernoulli, so codes match the
@@ -133,38 +151,45 @@ def _quant_codes(x, scale, bits: int, u=None):
     lv = _levels(bits)
     y = jnp.clip((x / scale + 1.0) * (0.5 * lv), 0.0, lv)
     if u is None:
-        return jnp.round(y).astype(jnp.uint32)
+        return jnp.round(y).astype(jnp.int32)
     lo = jnp.floor(y)
     bump = (u < (y - lo)).astype(jnp.float32)
-    return (lo + bump).astype(jnp.uint32)
+    return (lo + bump).astype(jnp.int32)
+
+
+def _join_planes(vals, planes: int, shift: int):
+    """(r, planes*w) i32 values < 2**shift -> (r, w) i32: lane block i
+    (lanes i*w ... (i+1)*w) lands at bit shift i*shift."""
+    w = vals.shape[-1] // planes
+    acc = vals[:, :w]
+    for i in range(1, planes):
+        acc = acc | (vals[:, i * w:(i + 1) * w] << (i * shift))
+    return acc
+
+
+def _split_planes(vals, planes: int, shift: int):
+    """Inverse of `_join_planes`: (r, w) i32 -> (r, planes*w) i32."""
+    if planes == 1:
+        return vals
+    mask = (1 << shift) - 1
+    return jnp.concatenate([(vals >> (i * shift)) & mask
+                            for i in range(planes)], axis=-1)
 
 
 def _pack(codes, bits: int):
-    """(r, d) u32 codes -> (r, d*bits/8) u8, k codes per byte."""
-    k = 8 // bits
-    r, d = codes.shape
-    grouped = codes.reshape(r, d // k, k)
-    shifts = (jnp.arange(k, dtype=jnp.uint32) * bits)[None, None, :]
-    return jnp.sum(grouped << shifts, axis=-1).astype(jnp.uint8)
+    """(r, d) i32 codes -> (r, d*bits/8) u8, planar, k codes per byte."""
+    return _join_planes(codes, 8 // bits, bits).astype(jnp.uint8)
 
 
 def _unpack(packed, bits: int):
-    """(r, pw) u8 -> (r, pw * 8/bits) u32 codes."""
-    k = 8 // bits
-    lv = _levels(bits)
-    shifts = (jnp.arange(k, dtype=jnp.uint32) * bits)[None, None, :]
-    vals = (packed.astype(jnp.uint32)[..., None] >> shifts) & jnp.uint32(lv)
-    return vals.reshape(packed.shape[0], -1)
+    """(r, pw) u8 -> (r, pw * 8/bits) i32 codes (inverse of `_pack`)."""
+    return _split_planes(packed.astype(jnp.int32), 8 // bits, bits)
 
 
 def _dequant(codes, scale, bits: int):
-    # must mirror core.quantization.dequantize op-for-op: 2c - lv is
-    # integer-exact and the trailing division blocks FMA contraction, so
-    # the fused kernel and the reference chain round identically under
-    # any compiler (the bit-identical backend contract).
-    lv = _levels(bits)
-    ic = codes.astype(jnp.float32) * 2.0 - float(lv)
-    return (ic * scale) / lv
+    # the reference chain's own formula, so both backends round
+    # identically (the bit-identical backend contract)
+    return Q.dequantize_sum_mean(codes, scale, bits, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -189,7 +214,7 @@ def _dqp_kernel(a_ref, m_ref, *rest, bits: int, noise: str):
                                              "interpret"))
 def delta_quantize_pack(a, m, u=None, *, bits: int, seed=None,
                         block_r: int = DEFAULT_BLOCK_R,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """a, m: (R, d); u: optional uniform noise (R, d) for stochastic
     rounding (or seed: (2,) i32 for the on-core PRNG path, TPU only).
     Returns (packed (R, d//(8/bits)) u8, scale (R, 1) f32,
@@ -219,7 +244,7 @@ def delta_quantize_pack(a, m, u=None, *, bits: int, seed=None,
             jax.ShapeDtypeStruct((r, 1), jnp.float32),
             jax.ShapeDtypeStruct((r, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(*args)
 
 
@@ -238,7 +263,7 @@ def _dua_kernel(packed_ref, scale_ref, m_ref, mnew_ref, *, bits: int):
                                              "interpret"))
 def dequant_unpack_accumulate(packed, scale, m, *, bits: int,
                               block_r: int = DEFAULT_BLOCK_R,
-                              interpret: bool = True):
+                              interpret: Optional[bool] = None):
     """packed (R, d//(8/bits)) u8, scale (R, 1) f32, m (R, d).
     Returns m_new (R, d) f32 — the receiver's reconstructed activation."""
     assert bits in (2, 4, 8), bits
@@ -257,7 +282,7 @@ def dequant_unpack_accumulate(packed, scale, m, *, bits: int,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.float32),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(packed, scale, m)
 
 
@@ -277,7 +302,7 @@ def _qp_kernel(x_ref, *rest, bits: int, noise: str):
                                              "interpret"))
 def quantize_pack(x, u=None, *, bits: int, seed=None,
                   block_r: int = DEFAULT_BLOCK_R,
-                  interpret: bool = True):
+                  interpret: Optional[bool] = None):
     """x: (R, d); u: optional uniform noise (R, d) (or seed: (2,) i32
     for the on-core PRNG path, TPU only).  Returns
     (packed (R, d//(8/bits)) u8, scale (R, 1) f32) — one fused pass for
@@ -306,7 +331,7 @@ def quantize_pack(x, u=None, *, bits: int, seed=None,
             jax.ShapeDtypeStruct((r, d // k), jnp.uint8),
             jax.ShapeDtypeStruct((r, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(*args)
 
 def _ud_kernel(packed_ref, scale_ref, out_ref, *, bits: int):
@@ -318,7 +343,8 @@ def _ud_kernel(packed_ref, scale_ref, out_ref, *, bits: int):
 @functools.partial(jax.jit, static_argnames=("bits", "block_r", "out_dtype",
                                              "interpret"))
 def unpack_dequant(packed, scale, *, bits: int, out_dtype=jnp.float32,
-                   block_r: int = DEFAULT_BLOCK_R, interpret: bool = True):
+                   block_r: int = DEFAULT_BLOCK_R,
+                   interpret: Optional[bool] = None):
     """packed (R, pw) u8, scale (R, 1) f32 -> values (R, pw * 8/bits) in
     out_dtype — one fused pass for the DirectQ/backward receiver and
     z-bit buffer reads."""
@@ -338,7 +364,7 @@ def unpack_dequant(packed, scale, *, bits: int, out_dtype=jnp.float32,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.dtype(out_dtype)),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(packed, scale)
 
 
@@ -362,7 +388,7 @@ def _qps_kernel(x_ref, s_ref, *rest, bits: int, stochastic: bool):
                                              "interpret"))
 def quantize_pack_scaled(x, s, u=None, *, bits: int,
                          block_r: int = DEFAULT_BLOCK_R,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """x: (R, d) values, s: (R, 1) caller-supplied rowwise scale (e.g. the
     pmax-shared scale of a compressed allreduce); u: optional uniform
     noise (R, d).  Returns packed (R, d//(8/bits)) u8 — one fused pass
@@ -386,18 +412,18 @@ def quantize_pack_scaled(x, s, u=None, *, bits: int,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, d // k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d // k), jnp.uint8),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(*args)
 
 
 def _uc_kernel(packed_ref, out_ref, *, bits: int):
-    out_ref[...] = _unpack(packed_ref[...], bits).astype(jnp.int32)
+    out_ref[...] = _unpack(packed_ref[...], bits)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block_r",
                                              "interpret"))
 def unpack_codes(packed, *, bits: int, block_r: int = DEFAULT_BLOCK_R,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """packed (R, pw) u8 -> (R, pw * 8/bits) int32 codes: the code-domain
     form a compressed allreduce accumulates with ``psum`` (int32 sums of
     b-bit codes are exact in any reduction order)."""
@@ -414,26 +440,21 @@ def unpack_codes(packed, *, bits: int, block_r: int = DEFAULT_BLOCK_R,
         in_specs=[pl.BlockSpec((br, pw), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.int32),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(packed)
 
 
 def _dsm_kernel(total_ref, s_ref, out_ref, *, bits: int, n: int):
-    # mean of n dequantized code tensors, given their exact int32 sum:
-    #   sum_i ((2 c_i - lv) s) / lv = ((2 T - n lv) s) / lv
-    # 2T - n*lv is integer-exact in f32 and the trailing divisions block
-    # FMA contraction — same association as _dequant, so the reference
-    # chain and this kernel round identically (the parity contract).
-    lv = _levels(bits)
-    ic = total_ref[...].astype(jnp.float32) * 2.0 - float(n * lv)
-    out_ref[...] = ((ic * s_ref[...]) / lv) / n
+    # mean of n dequantized code tensors, given their exact int32 sum
+    out_ref[...] = Q.dequantize_sum_mean(total_ref[...], s_ref[...],
+                                         bits, n)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "n", "block_r",
                                              "interpret"))
 def dequant_sum_mean(total, s, *, bits: int, n: int,
                      block_r: int = DEFAULT_BLOCK_R,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """total (R, d) int32 code sum over n workers, s (R, 1) shared scale.
     Returns the mean gradient (R, d) f32 — the receiver side of the
     compressed DP allreduce."""
@@ -452,7 +473,7 @@ def dequant_sum_mean(total, s, *, bits: int, n: int,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.float32),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(total, s)
 
 
@@ -472,14 +493,14 @@ def _qcs_kernel(x_ref, s_ref, *rest, bits: int, noise: str, pack: bool):
     codes = _quant_codes(x, scale, bits, u)
     if pack:
         packed_ref[...] = _pack(codes, bits)
-    codes_ref[...] = codes.astype(jnp.int32)
+    codes_ref[...] = codes
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "pack", "block_r",
                                              "interpret"))
 def quantize_codes_scaled(x, s, u=None, *, bits: int, pack: bool = False,
                           seed=None, block_r: int = DEFAULT_BLOCK_R,
-                          interpret: bool = True):
+                          interpret: Optional[bool] = None):
     """Codes-only encode: quantize x (R, d) against the caller-supplied
     rowwise scale s (R, 1) and emit int32 codes — the accumulator form a
     compressed allreduce sums — WITHOUT the pack→unpack round trip of
@@ -513,21 +534,20 @@ def quantize_codes_scaled(x, s, u=None, *, bits: int, pack: bool = False,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(*args)
     return tuple(out) if pack else out[0]
 
 
 def _ua_kernel(packed_ref, acc_ref, out_ref, *, bits: int):
-    out_ref[...] = acc_ref[...] + _unpack(packed_ref[...], bits
-                                          ).astype(jnp.int32)
+    out_ref[...] = acc_ref[...] + _unpack(packed_ref[...], bits)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block_r",
                                              "interpret"))
 def unpack_accumulate(packed, acc, *, bits: int,
                       block_r: int = DEFAULT_BLOCK_R,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """packed (R, pw) u8 incoming ring segment, acc (R, pw * 8/bits) i32
     local code accumulator.  Returns acc + unpack(packed) in ONE pass —
     the ring's accumulate step (the unpack the psum wire used to run as
@@ -549,7 +569,7 @@ def unpack_accumulate(packed, acc, *, bits: int,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.int32),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(packed, acc)
 
 
@@ -564,20 +584,19 @@ def _sum_geometry(bits: int, n: int) -> int:
 
 
 def _ps_kernel(total_ref, out_ref, *, sw: int):
-    t = total_ref[...].astype(jnp.uint32)
+    t = total_ref[...]
     if sw <= 8:
         out_ref[...] = _pack(t, sw)
     else:
-        nb = sw // 8
-        shifts = (jnp.arange(nb, dtype=jnp.uint32) * 8)[None, None, :]
-        b = (t[..., None] >> shifts) & jnp.uint32(0xFF)
-        out_ref[...] = b.reshape(t.shape[0], -1).astype(jnp.uint8)
+        # byte plane b holds the b-th little-endian byte of every sum
+        out_ref[...] = _split_planes(t, sw // 8, 8).astype(jnp.uint8)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "n", "block_r",
                                              "interpret"))
 def pack_sums(total, *, bits: int, n: int,
-              block_r: int = DEFAULT_BLOCK_R, interpret: bool = True):
+              block_r: int = DEFAULT_BLOCK_R,
+              interpret: Optional[bool] = None):
     """total (R, d) i32 code sums over n workers -> dense u8 payload at
     `sum_wire_bits(bits, n)` bits per sum — the ring's all-gather hop
     format (b + ceil(log2 n) bits is the exactness price of shipping
@@ -600,25 +619,23 @@ def pack_sums(total, *, bits: int, n: int,
         in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, pw), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, pw), jnp.uint8),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(total)
 
 
 def _us_kernel(packed_ref, out_ref, *, sw: int):
     p = packed_ref[...]
     if sw <= 8:
-        out_ref[...] = _unpack(p, sw).astype(jnp.int32)
+        out_ref[...] = _unpack(p, sw)
     else:
-        nb = sw // 8
-        shifts = (jnp.arange(nb, dtype=jnp.uint32) * 8)[None, None, :]
-        b = p.astype(jnp.uint32).reshape(p.shape[0], -1, nb)
-        out_ref[...] = jnp.sum(b << shifts, axis=-1).astype(jnp.int32)
+        out_ref[...] = _join_planes(p.astype(jnp.int32), sw // 8, 8)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "n", "block_r",
                                              "interpret"))
 def unpack_sums(packed, *, bits: int, n: int,
-                block_r: int = DEFAULT_BLOCK_R, interpret: bool = True):
+                block_r: int = DEFAULT_BLOCK_R,
+                interpret: Optional[bool] = None):
     """Inverse of `pack_sums`: u8 payload -> (R, d) i32 code sums."""
     assert bits in (2, 4, 8), bits
     sw = _sum_geometry(bits, n)
@@ -633,5 +650,5 @@ def unpack_sums(packed, *, bits: int, n: int,
         in_specs=[pl.BlockSpec((br, pw), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.int32),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(packed)

@@ -1,9 +1,12 @@
 """Pure-jnp oracles for the Pallas kernels (the correctness ground truth
-swept against in tests/test_kernels.py)."""
+swept against in tests/test_kernels.py).  The byte layout is the one
+`repro.core.quantization` defines; the oracles pack through it."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from repro.core import quantization as Q
 
 _EPS = 1e-12
 
@@ -17,22 +20,6 @@ def _codes_ref(x, scale, bits: int, u=None):
     return (lo + (u < (y - lo)).astype(jnp.float32)).astype(jnp.uint8)
 
 
-def _pack_ref(codes, bits: int):
-    k = 8 // bits
-    r, d = codes.shape
-    grouped = codes.reshape(r, d // k, k).astype(jnp.uint32)
-    shifts = jnp.arange(k, dtype=jnp.uint32) * bits
-    return jnp.sum(grouped << shifts, axis=-1).astype(jnp.uint8)
-
-
-def _dequant_ref(codes, scale, bits: int):
-    """Same association as core.quantization.dequantize (2c - lv exact,
-    trailing division) so the oracle is FMA-contraction-proof too."""
-    levels = (1 << bits) - 1
-    ic = codes.astype(jnp.float32) * 2.0 - float(levels)
-    return (ic * scale) / levels
-
-
 def delta_quantize_pack_ref(a, m, bits: int, u=None):
     """AQ-SGD sender side: delta -> rowwise absmax scale -> b-bit codes ->
     dense uint8 packing.  a, m: (R, d) float; u: optional uniform noise
@@ -42,8 +29,8 @@ def delta_quantize_pack_ref(a, m, bits: int, u=None):
     scale = jnp.maximum(jnp.max(jnp.abs(delta), axis=-1, keepdims=True),
                         _EPS)
     codes = _codes_ref(delta, scale, bits, u)
-    packed = _pack_ref(codes, bits)
-    m_new = m.astype(jnp.float32) + _dequant_ref(codes, scale, bits)
+    packed = Q.pack_codes(codes, bits)
+    m_new = m.astype(jnp.float32) + Q.dequantize(codes, scale, bits)
     return packed, scale, m_new
 
 
@@ -51,7 +38,7 @@ def quantize_pack_ref(x, bits: int, u=None):
     """DirectQ/backward/buffer sender side: absmax -> codes -> packing."""
     x = x.astype(jnp.float32)
     scale = jnp.maximum(jnp.max(jnp.abs(x), axis=-1, keepdims=True), _EPS)
-    return _pack_ref(_codes_ref(x, scale, bits, u), bits), scale
+    return Q.pack_codes(_codes_ref(x, scale, bits, u), bits), scale
 
 
 def unpack_dequant_ref(packed, scale, bits: int):
@@ -64,14 +51,8 @@ def unpack_dequant_ref(packed, scale, bits: int):
 def dequant_unpack_accumulate_ref(packed, scale, m, bits: int):
     """AQ-SGD receiver side: unpack -> dequantize -> m += delta.
     packed: (R, d*b/8) u8; scale (R, 1); m (R, d).  Returns m_new f32."""
-    k = 8 // bits
-    levels = (1 << bits) - 1
-    shifts = jnp.arange(k, dtype=jnp.uint32) * bits
-    mask = jnp.uint32(levels)
-    vals = (packed[..., None].astype(jnp.uint32) >> shifts) & mask
-    r = packed.shape[0]
-    codes = vals.reshape(r, -1)
-    return m.astype(jnp.float32) + _dequant_ref(codes, scale, bits)
+    codes = Q.unpack_codes(packed, bits, m.shape[-1])
+    return m.astype(jnp.float32) + Q.dequantize(codes, scale, bits)
 
 
 def quantize_pack_scaled_ref(x, s, bits: int, u=None):
@@ -80,17 +61,13 @@ def quantize_pack_scaled_ref(x, s, bits: int, u=None):
     the scale already lives on every worker."""
     x = x.astype(jnp.float32)
     scale = jnp.maximum(s.astype(jnp.float32), _EPS)
-    return _pack_ref(_codes_ref(x, scale, bits, u), bits)
+    return Q.pack_codes(_codes_ref(x, scale, bits, u), bits)
 
 
 def unpack_codes_ref(packed, bits: int):
     """Wire payload -> int32 codes (the psum accumulator form)."""
-    k = 8 // bits
-    levels = (1 << bits) - 1
-    shifts = jnp.arange(k, dtype=jnp.uint32) * bits
-    vals = (packed[..., None].astype(jnp.uint32) >> shifts) \
-        & jnp.uint32(levels)
-    return vals.reshape(packed.shape[0], -1).astype(jnp.int32)
+    d = packed.shape[-1] * (8 // bits)
+    return Q.unpack_codes(packed, bits, d).astype(jnp.int32)
 
 
 def quantize_codes_scaled_ref(x, s, bits: int, u=None, pack: bool = False):
@@ -101,7 +78,7 @@ def quantize_codes_scaled_ref(x, s, bits: int, u=None, pack: bool = False):
     scale = jnp.maximum(s.astype(jnp.float32), _EPS)
     codes = _codes_ref(x, scale, bits, u)
     if pack:
-        return _pack_ref(codes, bits), codes.astype(jnp.int32)
+        return Q.pack_codes(codes, bits), codes.astype(jnp.int32)
     return codes.astype(jnp.int32)
 
 
@@ -110,53 +87,23 @@ def unpack_accumulate_ref(packed, acc, bits: int):
     return acc.astype(jnp.int32) + unpack_codes_ref(packed, bits)
 
 
-def _sum_width_ref(bits: int, n: int) -> int:
-    maxv = n * ((1 << bits) - 1)
-    for sw in (1, 2, 4, 8, 16, 32):
-        if maxv <= (1 << sw) - 1:
-            return sw
-    raise ValueError((bits, n))
-
-
 def pack_sums_ref(total, bits: int, n: int):
     """Code-sum packing oracle: i32 sums over n workers -> u8 payload at
     the narrowest width holding n*(2**bits - 1)."""
-    sw = _sum_width_ref(bits, n)
-    t = total.astype(jnp.uint32)
-    if sw <= 8:
-        k = 8 // sw
-        r, d = t.shape
-        grouped = t.reshape(r, d // k, k)
-        shifts = jnp.arange(k, dtype=jnp.uint32) * sw
-        return jnp.sum(grouped << shifts, axis=-1).astype(jnp.uint8)
-    nb = sw // 8
-    shifts = jnp.arange(nb, dtype=jnp.uint32) * 8
-    b = (t[..., None] >> shifts) & jnp.uint32(0xFF)
-    return b.reshape(t.shape[0], -1).astype(jnp.uint8)
+    return Q.pack_sums(total, bits, n)
 
 
 def unpack_sums_ref(packed, bits: int, n: int):
     """Inverse of pack_sums_ref (full packed width)."""
-    sw = _sum_width_ref(bits, n)
-    if sw <= 8:
-        k = 8 // sw
-        shifts = jnp.arange(k, dtype=jnp.uint32) * sw
-        vals = (packed[..., None].astype(jnp.uint32) >> shifts) \
-            & jnp.uint32((1 << sw) - 1)
-        return vals.reshape(packed.shape[0], -1).astype(jnp.int32)
-    nb = sw // 8
-    shifts = jnp.arange(nb, dtype=jnp.uint32) * 8
-    b = packed.astype(jnp.uint32).reshape(packed.shape[0], -1, nb)
-    return jnp.sum(b << shifts, axis=-1).astype(jnp.int32)
+    sw = Q.sum_wire_bits(bits, n)
+    pw = packed.shape[-1]
+    d = pw * (8 // sw) if sw <= 8 else pw // (sw // 8)
+    return Q.unpack_sums(packed, bits, n, d)
 
 
 def dequant_sum_mean_ref(total, s, bits: int, n: int):
-    """Int32 code sum over n workers + shared scale -> mean gradient.
-    Same association as _dequant_ref (2T - n*lv exact, trailing
-    divisions) so the oracle is FMA-contraction-proof too."""
-    levels = (1 << bits) - 1
-    ic = total.astype(jnp.float32) * 2.0 - float(n * levels)
-    return ((ic * s) / levels) / n
+    """Int32 code sum over n workers + shared scale -> mean gradient."""
+    return Q.dequantize_sum_mean(total, s, bits, n)
 
 
 def flash_attention_ref(q, k, v, *, causal=True, window=10 ** 9,

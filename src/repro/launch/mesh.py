@@ -7,47 +7,30 @@ before the first jax device query.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - older jax: Auto is the default
-    AxisType = None
-
-try:  # jax >= 0.5
-    from jax import shard_map as _shard_map
-    _SM_KW = {"check_vma": False}
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_KW = {"check_rep": False}
+from jax.sharding import AxisType
 
 
-def _mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh_auto(shape, axes):
+    """Mesh with all axes in Auto (collective) mode."""
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_mesh_auto(shape, axes):
-    """Version-portable mesh with all axes in Auto (collective) mode."""
-    return _mesh(shape, axes)
-
-
 def shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable jax.shard_map with replication checking off."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **_SM_KW)
+    """jax.shard_map with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
+    return make_mesh_auto(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2):
     """Small mesh for in-container multi-device tests (8 host devices)."""
-    return _mesh((data, model), ("data", "model"))
+    return make_mesh_auto((data, model), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple:

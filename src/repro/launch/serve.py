@@ -62,12 +62,14 @@ def main():
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from repro import env
     from repro.configs.base import get_config
     from repro.launch.mesh import make_debug_mesh
     from repro.models import model as Mo
     from repro.serving import (ContinuousBatcher, DeltaHopCodec, KVCodec,
                                quantize_caches)
 
+    env.use_compile_cache()
     comm = comm_cli.from_args(args)
     print("comm:", comm.to_json())
     cfg = get_config(args.arch, smoke=args.smoke)

@@ -107,13 +107,13 @@ def main():
         print_wires()
         return
 
-    import jax
-    import jax.numpy as jnp
+    from repro import env
     from repro.configs.base import get_config
     from repro.data.pipeline import Dataset, DatasetConfig
     from repro.optim.adamw import AdamWConfig
     from repro.checkpoint import checkpoint as ckpt
 
+    env.use_compile_cache()
     comm = comm_cli.from_args(args)
     cfg = get_config(args.arch, smoke=args.smoke)
     dc = DatasetConfig(num_samples=args.samples, seq_len=args.seq,
@@ -154,84 +154,105 @@ def main():
             print("saved", args.checkpoint)
         return
 
-    # ---- distributed shard_map pipeline ------------------------------------
+    run_distributed(cfg, comm, ds, opt, stages=args.stages,
+                    data_par=args.data_par,
+                    microbatches=args.microbatches, batch=args.batch,
+                    seq=args.seq, samples=args.samples,
+                    steps=args.steps, warmup_epochs=args.warmup_epochs,
+                    ckpt_dir=args.ckpt_dir, save_every=args.save_every,
+                    keep=args.keep, resume=args.resume)
+
+
+def run_distributed(cfg, comm, ds, opt, *, stages: int, data_par: int,
+                    microbatches: int, batch: int, seq: int, samples: int,
+                    steps: int, warmup_epochs: int = 1, ckpt_dir: str = "",
+                    save_every: int = 0, keep: int = 3,
+                    resume: bool = False, log_every: int = 10,
+                    key=None, print_fn=print):
+    """The shard_map GPipe pipeline on a (data_par, stages) mesh of the
+    visible devices — the ``--distributed`` path.  Returns ``(state,
+    losses)``, one loss per step this call ran."""
+    import jax
+    import jax.numpy as jnp
+    from repro.checkpoint import checkpoint as ckpt
     from repro.launch.mesh import make_debug_mesh
     from repro.models import model as Mo
     from repro.optim import adamw
     from repro.training import pipeline as PL
 
-    mesh = make_debug_mesh(args.data_par, args.stages)
-    pcfg = PL.PipelineConfig(microbatches=args.microbatches,
-                             comm=comm, warmup=True)
-    gb = args.batch
-    step_w, meta = PL.make_train_step(cfg, pcfg, mesh, opt,
-                                      global_batch=gb, seq_len=args.seq,
-                                      buffer_samples=args.samples
-                                      // args.data_par)
-    pcfg2 = PL.PipelineConfig(microbatches=args.microbatches,
-                              comm=comm, warmup=False)
-    step_c, _ = PL.make_train_step(cfg, pcfg2, mesh, opt,
-                                   global_batch=gb, seq_len=args.seq,
-                                   buffer_samples=args.samples
-                                   // args.data_par)
-    params = PL.to_pipeline_params(
-        cfg, Mo.init_params(cfg, jax.random.PRNGKey(0)), args.stages)
-    if comm.dp.bits and comm.dp_wire_spec.sharded:
-        opt_state = PL.init_sharded_opt(pcfg, params, args.data_par)
-    else:
-        opt_state = adamw.init_opt_state(params)
-    state = {"params": params, "opt": opt_state}
-    if comm.dp.bits:
-        state["dp_error"] = PL.init_dp_error(pcfg, params, args.data_par)
-    if comm.mode == "aqsgd":
-        n_loc = args.samples // args.data_par
-        structs = PL.buffer_structs(pcfg, args.stages,
-                                    args.data_par * n_loc, args.seq,
-                                    cfg.d_model)
-        zeros = lambda s: jnp.zeros(s.shape, s.dtype)
-        state["m_out"] = jax.tree.map(zeros, structs)
-        state["m_in"] = jax.tree.map(zeros, structs)
+    mesh = make_debug_mesh(data_par, stages)
+    steps_w = {}
+    for warm in (True, False):
+        pcfg = PL.PipelineConfig(microbatches=microbatches, comm=comm,
+                                 warmup=warm)
+        steps_w[warm], meta = PL.make_train_step(
+            cfg, pcfg, mesh, opt, global_batch=batch, seq_len=seq,
+            buffer_samples=samples // data_par)
+
+    def init_state():
+        params = PL.to_pipeline_params(
+            cfg, Mo.init_params(cfg, key if key is not None
+                                else jax.random.PRNGKey(0)), stages)
+        if comm.dp.bits and comm.dp_wire_spec.sharded:
+            opt_state = PL.init_sharded_opt(pcfg, params, data_par)
+        else:
+            opt_state = adamw.init_opt_state(params)
+        state = {"params": params, "opt": opt_state}
+        if comm.dp.bits:
+            state["dp_error"] = PL.init_dp_error(pcfg, params, data_par)
+        if comm.mode == "aqsgd":
+            n_loc = samples // data_par
+            structs = PL.buffer_structs(pcfg, stages, data_par * n_loc,
+                                        seq, cfg.d_model)
+            zeros = lambda s: jnp.zeros(s.shape, s.dtype)
+            state["m_out"] = jax.tree.map(zeros, structs)
+            state["m_in"] = jax.tree.map(zeros, structs)
+        return state
+
+    # built in place on the step's shardings: each device holds only its
+    # own stage's slice, never the whole model
+    state = jax.jit(init_state, out_shardings=meta["state_specs"])()
 
     start = 0
-    if args.ckpt_dir:
-        removed = ckpt.clean_orphans(args.ckpt_dir)
+    if ckpt_dir:
+        removed = ckpt.clean_orphans(ckpt_dir)
         if removed:
-            print(f"checkpoint: removed {len(removed)} orphaned tmp "
-                  f"entries")
-    if args.resume:
-        state, body = ckpt.restore_state(args.ckpt_dir,
+            print_fn(f"checkpoint: removed {len(removed)} orphaned tmp "
+                     f"entries")
+    if resume:
+        state, body = ckpt.restore_state(ckpt_dir,
                                          jax.eval_shape(lambda: state),
                                          comm=comm)
         start = int(body["step"])
-        print(f"resumed from step {start}")
+        print_fn(f"resumed from step {start}")
 
-    m = args.microbatches
-    steps_per_epoch = max(args.samples // gb, 1)
-    key = jax.random.PRNGKey(1)
-    batches = ds.batches(gb, args.steps)
+    m = microbatches
+    steps_per_epoch = max(samples // batch, 1)
+    step_key = jax.random.PRNGKey(1)
+    ds.reset()          # the batch stream is a function of the config
+    batches = ds.batches(batch, steps)
     for _ in range(start):
         next(batches)   # the data stream is deterministic: replay by
                         # skipping to the checkpointed position
-    metrics = None
-    for step_i, batch in enumerate(batches, start=start):
-        batch = {k: jnp.asarray(v).reshape(m, gb // m, *v.shape[1:])
-                 for k, v in batch.items()}
-        fn = step_w if (comm.mode == "aqsgd"
-                        and step_i < steps_per_epoch
-                        * args.warmup_epochs) else step_c
-        state, metrics = fn(state, batch, jax.random.fold_in(key, step_i))
-        if step_i % 10 == 0:
-            loss = float(metrics["loss"])
-            print(f"step {step_i:5d} loss {loss:.4f} [{loss.hex()}]")
+    losses = []
+    for step_i, b in enumerate(batches, start=start):
+        b = {k: jnp.asarray(v).reshape(m, batch // m, *v.shape[1:])
+             for k, v in b.items()}
+        warm = comm.mode == "aqsgd" \
+            and step_i < steps_per_epoch * warmup_epochs
+        state, metrics = steps_w[warm](state, b,
+                                       jax.random.fold_in(step_key, step_i))
+        loss = float(metrics["loss"])
+        losses.append(loss)
+        if log_every and step_i % log_every == 0:
+            print_fn(f"step {step_i:5d} loss {loss:.4f} [{loss.hex()}]")
         done = step_i + 1
-        if args.ckpt_dir and args.save_every \
-                and done % args.save_every == 0:
-            ckpt.save_state(args.ckpt_dir, state, step=done, comm=comm,
-                            extra={"data_position": done},
-                            keep=args.keep)
-    if metrics is not None:
-        print(f"final loss {float(metrics['loss']):.4f}")
-
+        if ckpt_dir and save_every and done % save_every == 0:
+            ckpt.save_state(ckpt_dir, state, step=done, comm=comm,
+                            extra={"data_position": done}, keep=keep)
+    if losses:
+        print_fn(f"final loss {losses[-1]:.4f}")
+    return state, losses
 
 if __name__ == "__main__":
     main()

@@ -826,7 +826,14 @@ def make_pipeline_fn(cfg: ModelConfig, pcfg: PipelineConfig,
             ids_r = jax.lax.dynamic_index_in_dim(ids, jr, 0, keepdims=False)
             if not has_bufs:
                 mo_s = mi_s = jnp.zeros_like(out, jnp.float32)
-            recv, nmo, nmi = transfer(out, mo_s, mi_s,
+            # a message no stage uses (bubble ticks, and the last stage's
+            # wrap-around hop) goes out as zeros, which cuts its backward
+            # path: a compressed backward would hand it quantization
+            # noise (a zero row decodes to +-scale/levels, not 0), and
+            # the stage's Jacobian at bubble inputs amplifies that past
+            # f32 range within a dozen layers — NaN parameters
+            send = jnp.where(valid_p & (k < K - 1), out, 0)
+            recv, nmo, nmi = transfer(send, mo_s, mi_s,
                                       jax.random.fold_in(key, t))
             if has_bufs:
                 mo = buffer_write(pcfg, mo, ids_s, nmo,

@@ -206,6 +206,24 @@ def test_manifest_byteflip_fails_closed(tmp_path):
         ckpt.restore_state(str(tmp_path), jax.eval_shape(lambda: tree))
 
 
+def test_format_version_1_refused(tmp_path):
+    """Version 1 stored packed buffers in the interleaved byte layout:
+    same shapes and dtypes, different codes — a valid v1 manifest must
+    be refused, not unpacked as planar bytes."""
+    tree = make_tree()
+    path = ckpt.save_state(str(tmp_path), tree, step=1)
+    mpath = os.path.join(path, ckpt.MANIFEST_NAME)
+    manifest = json.load(open(mpath))
+    manifest["body"]["format_version"] = 1
+    manifest["crc32"] = zlib.crc32(
+        ckpt.checkpoint._canonical(manifest["body"]))
+    json.dump(manifest, open(mpath, "w"), sort_keys=True,
+              separators=(",", ":"))
+    with pytest.raises(ckpt.CheckpointError,
+                       match="format_version 1 != supported 2"):
+        ckpt.restore_state(str(tmp_path), jax.eval_shape(lambda: tree))
+
+
 # ---------------------------------------------------------------------------
 # loud mismatch diffs (satellite b)
 # ---------------------------------------------------------------------------

@@ -579,6 +579,29 @@ def test_gradient_path_has_no_unfused_quantize_calls():
     assert run_rule("no-unfused-quantize") == []
 
 
+@pytest.mark.parametrize("wire", ["ring_ef_reduce_mean_bucket",
+                                  "ring_ef_reduce_scatter_bucket"])
+def test_ring_segments_are_row_slices(wire):
+    """The ring cuts its (n*seg, .) bucket into segments by row slices,
+    never by a reshape to or from (n, seg, .): on a TPU that reshape is
+    a relayout when seg is not a multiple of the row tile, and compiles
+    in time linear in the rows — minutes for a 1.5B-parameter bucket."""
+    from repro.core import collectives as C
+
+    n, seg, d = 2, 37, 128
+    rows = n * seg - 1                       # a ragged last segment
+    fn = functools.partial(getattr(C, wire), axis_name="data", bits=4,
+                           backend="reference")
+    jaxpr = jax.make_jaxpr(
+        lambda v, e, k: fn(v, e, key=k), axis_env=[("data", n)])(
+        jnp.zeros((rows, d)), jnp.zeros((rows, d)), jax.random.PRNGKey(0))
+    split = [(tuple(e.invars[0].aval.shape), tuple(o.aval.shape))
+             for e in jaxpr.jaxpr.eqns if e.primitive.name == "reshape"
+             for o in e.outvars]
+    assert not [s for s in split
+                if (n, seg) in (s[0][:2], s[1][:2])], split
+
+
 # ---------------------------------------------------------------------------
 # Fig. 5a convergence regression (slow tier -> nightly CI)
 # ---------------------------------------------------------------------------
