@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import boundary as B
 from repro.core import quantization as Q
 
@@ -101,6 +102,7 @@ def buffer_nbytes(cc: CompressionConfig, num_boundaries: int,
     return nb * num_samples * seq * d * jnp.dtype(cc.buffer_dtype).itemsize
 
 
+@jax.named_scope(tracing.STORE)
 def read_buffer(cc: CompressionConfig, bufs: dict, boundary: int,
                 sample_ids: jax.Array, d: int) -> jax.Array:
     """-> m (B, S, d) float32 for the given samples."""
@@ -112,6 +114,7 @@ def read_buffer(cc: CompressionConfig, bufs: dict, boundary: int,
     return bufs["m"][boundary][sample_ids].astype(jnp.float32)
 
 
+@jax.named_scope(tracing.STORE)
 def write_buffer(cc: CompressionConfig, bufs: dict, boundary: int,
                  sample_ids: jax.Array, m_new: jax.Array) -> dict:
     """Store the updated messages for `sample_ids` at one boundary
@@ -164,6 +167,7 @@ def _make_ste(bw_bits: int, stochastic: bool, backend: str):
     return ste
 
 
+@jax.named_scope(tracing.BOUNDARY)
 def apply_boundary(cc: CompressionConfig, h: jax.Array, key: jax.Array,
                    m: Optional[jax.Array] = None,
                    seen: Optional[jax.Array] = None,

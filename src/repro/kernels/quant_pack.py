@@ -244,6 +244,7 @@ def delta_quantize_pack(a, m, u=None, *, bits: int, seed=None,
             jax.ShapeDtypeStruct((r, 1), jnp.float32),
             jax.ShapeDtypeStruct((r, d), jnp.float32),
         ],
+        name="delta_quantize_pack",
         interpret=_interpret(interpret),
     )(*args)
 
@@ -282,6 +283,7 @@ def dequant_unpack_accumulate(packed, scale, m, *, bits: int,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.float32),
+        name="dequant_unpack_accumulate",
         interpret=_interpret(interpret),
     )(packed, scale, m)
 
@@ -331,6 +333,7 @@ def quantize_pack(x, u=None, *, bits: int, seed=None,
             jax.ShapeDtypeStruct((r, d // k), jnp.uint8),
             jax.ShapeDtypeStruct((r, 1), jnp.float32),
         ],
+        name="quantize_pack",
         interpret=_interpret(interpret),
     )(*args)
 
@@ -364,6 +367,7 @@ def unpack_dequant(packed, scale, *, bits: int, out_dtype=jnp.float32,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.dtype(out_dtype)),
+        name="unpack_dequant",
         interpret=_interpret(interpret),
     )(packed, scale)
 
@@ -412,6 +416,7 @@ def quantize_pack_scaled(x, s, u=None, *, bits: int,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, d // k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d // k), jnp.uint8),
+        name="quantize_pack_scaled",
         interpret=_interpret(interpret),
     )(*args)
 
@@ -440,6 +445,7 @@ def unpack_codes(packed, *, bits: int, block_r: int = DEFAULT_BLOCK_R,
         in_specs=[pl.BlockSpec((br, pw), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.int32),
+        name="unpack_codes",
         interpret=_interpret(interpret),
     )(packed)
 
@@ -473,6 +479,7 @@ def dequant_sum_mean(total, s, *, bits: int, n: int,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.float32),
+        name="dequant_sum_mean",
         interpret=_interpret(interpret),
     )(total, s)
 
@@ -534,6 +541,7 @@ def quantize_codes_scaled(x, s, u=None, *, bits: int, pack: bool = False,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        name="quantize_codes_scaled",
         interpret=_interpret(interpret),
     )(*args)
     return tuple(out) if pack else out[0]
@@ -569,6 +577,7 @@ def unpack_accumulate(packed, acc, *, bits: int,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.int32),
+        name="unpack_accumulate",
         interpret=_interpret(interpret),
     )(packed, acc)
 
@@ -619,6 +628,7 @@ def pack_sums(total, *, bits: int, n: int,
         in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, pw), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, pw), jnp.uint8),
+        name="pack_sums",
         interpret=_interpret(interpret),
     )(total)
 
@@ -650,5 +660,6 @@ def unpack_sums(packed, *, bits: int, n: int,
         in_specs=[pl.BlockSpec((br, pw), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.int32),
+        name="unpack_sums",
         interpret=_interpret(interpret),
     )(packed)

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import checkpoint as ckpt
+from repro import tracing
 from repro.comm import faults as F
 
 KILL_EXIT_CODE = 17   # --kill-at's os._exit status: distinguishable
@@ -89,6 +90,7 @@ def run_sim_training(mcfg, tcfg, dataset, *, num_steps: int,
                      max_retries: int = 2,
                      fault_plan: Optional[F.FaultPlan] = None,
                      kill_at: Optional[int] = None, key=None,
+                     profile: Optional[tracing.StepProfile] = None,
                      print_fn=print):
     """Run the simulated trainer with checkpoint/resume, deterministic
     fault injection, and guarded recovery (module docstring).  Returns
@@ -130,9 +132,19 @@ def run_sim_training(mcfg, tcfg, dataset, *, num_steps: int,
     save_tree = lambda st: {"state": st, "k_run": _key_data(k_run)}
     like = jax.eval_shape(save_tree, state)
 
+    def save(step_done: int, tail: list):
+        tree = save_tree(state)
+        nbytes = sum(x.nbytes for x in jax.tree.leaves(tree))
+        with tracing.span("ckpt.save", bytes=nbytes):
+            ckpt.save_state(
+                ckpt_dir, tree, step=step_done, comm=comm,
+                extra={"losses_tail": [float(x) for x in tail[-5:]],
+                       "data_position": step_done}, keep=keep)
+
     start, loss_tail = 0, []
     if resume:
-        tree, body = ckpt.restore_state(ckpt_dir, like, comm=comm)
+        with tracing.span("ckpt.restore"):
+            tree, body = ckpt.restore_state(ckpt_dir, like, comm=comm)
         if not np.array_equal(np.asarray(tree["k_run"]),
                               _key_data(k_run)):
             raise ckpt.CheckpointError(
@@ -143,81 +155,88 @@ def run_sim_training(mcfg, tcfg, dataset, *, num_steps: int,
         print_fn(f"resumed from step {start} "
                  f"({ckpt.resolve_checkpoint(ckpt_dir)})")
     elif ckpt_dir and save_every:
-        ckpt.save_state(ckpt_dir, save_tree(state), step=0, comm=comm,
-                        extra={"losses_tail": [], "data_position": 0},
-                        keep=keep)
-
-    def save(step_done: int, tail: list):
-        ckpt.save_state(
-            ckpt_dir, save_tree(state), step=step_done, comm=comm,
-            extra={"losses_tail": [float(x) for x in tail[-5:]],
-                   "data_position": step_done}, keep=keep)
+        save(0, [])
 
     guard_state = bool(plan or (ckpt_dir and save_every))
     it = _skip_batches(dataset, batch_size, num_steps, start)
     it_pos = start
     fired = {s for s in plan.faults if s.step < start}
     losses, retries, step = [], 0, start
-    while step < num_steps:
-        if it_pos != step:
-            it = _skip_batches(dataset, batch_size, num_steps, step)
-            it_pos = step
-        batch = {k: jnp.asarray(v) for k, v in next(it).items()}
-        it_pos += 1
+    try:
+        while step < num_steps:
+            if profile:
+                profile.at(step)
+            with tracing.step_span(step):
+                if it_pos != step:
+                    it = _skip_batches(dataset, batch_size, num_steps, step)
+                    it_pos = step
+                with tracing.span("feed"):
+                    batch = {k: jnp.asarray(v) for k, v in next(it).items()}
+                it_pos += 1
 
-        step_tcfg = tcfg
-        post_step = []
-        for spec in plan.at(step):
-            if spec in fired:
-                continue
-            fired.add(spec)
-            print_fn(f"injecting fault {spec.text()}")
-            if spec.plane == "dp":
-                step_tcfg = tcfg.with_comm(F.faulted_comm(comm, spec))
-            elif spec.plane == "bw":
-                # a corrupt backward hop lands in the params at the
-                # UPDATE — after the forward wrote clean messages —
-                # so bw injection follows the step (guard attribution
-                # depends on this timing; see faults.inject_sim_state)
-                post_step.append(spec)
-            else:
-                state = F.inject_sim_state(state, spec, comm)
+                step_tcfg = tcfg
+                post_step = []
+                for spec in plan.at(step):
+                    if spec in fired:
+                        continue
+                    fired.add(spec)
+                    print_fn(f"injecting fault {spec.text()}")
+                    if spec.plane == "dp":
+                        step_tcfg = tcfg.with_comm(F.faulted_comm(comm, spec))
+                    elif spec.plane == "bw":
+                        # a corrupt backward hop lands in the params at the
+                        # UPDATE — after the forward wrote clean messages —
+                        # so bw injection follows the step (guard attribution
+                        # depends on this timing; see faults.inject_sim_state)
+                        post_step.append(spec)
+                    else:
+                        with tracing.span("fault", fault=spec.text()):
+                            state = F.inject_sim_state(state, spec, comm)
 
-        state, metrics = sim.train_step(
-            state, batch, jax.random.fold_in(k_run, step),
-            mcfg=mcfg, tcfg=step_tcfg)
-        for spec in post_step:
-            state = F.inject_sim_state(state, spec, comm)
-        loss = float(metrics["loss"])
-        try:
-            F.check_train_state(state if guard_state else {},
-                                comm=comm, step=step, loss=loss)
-        except F.WireFaultError as e:
-            print_fn(f"guard tripped: {e}")
-            retries += 1
-            if not ckpt_dir or retries > max_retries:
-                raise
-            tree, body = ckpt.restore_state(ckpt_dir, like, comm=comm)
-            state, step = tree["state"], int(body["step"])
-            loss_tail = list(body["extra"].get("losses_tail", []))
-            losses = [x for x in losses][:max(step - start, 0)]
-            print_fn(f"recovered from checkpoint step {step} "
-                     f"(retry {retries}/{max_retries})")
-            continue
+                with tracing.span("dispatch"):
+                    state, metrics = sim.train_step(
+                        state, batch, jax.random.fold_in(k_run, step),
+                        mcfg=mcfg, tcfg=step_tcfg)
+                for spec in post_step:
+                    with tracing.span("fault", fault=spec.text()):
+                        state = F.inject_sim_state(state, spec, comm)
+                with tracing.span("sync"):
+                    loss = float(metrics["loss"])
+                try:
+                    with tracing.span("guard"):
+                        F.check_train_state(state if guard_state else {},
+                                            comm=comm, step=step, loss=loss)
+                except F.WireFaultError as e:
+                    print_fn(f"guard tripped: {e}")
+                    retries += 1
+                    if not ckpt_dir or retries > max_retries:
+                        raise
+                    with tracing.span("ckpt.restore"):
+                        tree, body = ckpt.restore_state(ckpt_dir, like,
+                                                        comm=comm)
+                    state, step = tree["state"], int(body["step"])
+                    loss_tail = list(body["extra"].get("losses_tail", []))
+                    losses = [x for x in losses][:max(step - start, 0)]
+                    print_fn(f"recovered from checkpoint step {step} "
+                             f"(retry {retries}/{max_retries})")
+                    continue
 
-        losses.append(loss)
-        loss_tail = (loss_tail + [loss])[-5:]
-        if log_every and step % log_every == 0:
-            print_fn(_loss_line(step, loss))
-        if kill_at is not None and step == kill_at:
-            print_fn(f"killing at step {step} (exit {KILL_EXIT_CODE})")
-            # simulate a hard preemption: no save, no cleanup, no
-            # python teardown — the next run must recover from the
-            # last committed checkpoint alone
-            os._exit(KILL_EXIT_CODE)
-        step += 1
-        if ckpt_dir and save_every and step % save_every == 0:
-            save(step, loss_tail)
+                losses.append(loss)
+                loss_tail = (loss_tail + [loss])[-5:]
+                if log_every and step % log_every == 0:
+                    print_fn(_loss_line(step, loss))
+                if kill_at is not None and step == kill_at:
+                    print_fn(f"killing at step {step} (exit {KILL_EXIT_CODE})")
+                    # simulate a hard preemption: no save, no cleanup, no
+                    # python teardown — the next run must recover from the
+                    # last committed checkpoint alone
+                    os._exit(KILL_EXIT_CODE)
+                step += 1
+                if ckpt_dir and save_every and step % save_every == 0:
+                    save(step, loss_tail)
+    finally:
+        if profile:
+            profile.close()
 
     if ckpt_dir and save_every and num_steps % save_every != 0:
         save(num_steps, loss_tail)

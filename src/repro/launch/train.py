@@ -20,6 +20,8 @@ Examples:
       --data-par 4 --stages 2 --steps 10
   python -m repro.launch.train --smoke --steps 10 \\
       --comm-config '{"mode": "aqsgd", "dp": {"bits": 4, "wire": "fp16"}}'
+  python -m repro.launch.train --smoke --steps 30 \\
+      --profile-dir /tmp/prof --profile-steps 10:20
 """
 from __future__ import annotations
 
@@ -42,6 +44,17 @@ def print_wires() -> None:
     print(f"{'plane':{wp}}  {'wire':{wn}}  {'':{wf}}  summary")
     for p, n, f, s in rows:
         print(f"{p:{wp}}  {n:{wn}}  {f:{wf}}  {s}")
+
+
+def _step_range(text: str) -> tuple:
+    """``A:B`` -> (A, B), the steps A to B-1."""
+    try:
+        a, b = (int(x) for x in text.split(":"))
+    except ValueError:
+        raise argparse.ArgumentTypeError(f"expected A:B, got {text!r}")
+    if not 0 <= a < b:
+        raise argparse.ArgumentTypeError(f"need 0 <= A < B, got {text!r}")
+    return a, b
 
 
 def main():
@@ -96,6 +109,15 @@ def main():
                          "'3:dp:nan-scale,5:fw:drop-hop' (kinds: "
                          "corrupt-codes, nan-scale, drop-hop; "
                          "single-host trainer only)")
+    ap.add_argument("--profile-dir", default="",
+                    help="write a jax.profiler trace of --profile-steps "
+                         "under this directory: the device ops beside "
+                         "the host spans repro.step > feed, dispatch, "
+                         "sync, guard (single-host trainer only)")
+    ap.add_argument("--profile-steps", type=_step_range, default=(1, 11),
+                    metavar="A:B",
+                    help="steps A to B-1 to profile (default 1:11, "
+                         "which leaves out step 0's compile)")
     ap.add_argument("--kill-at", type=int, default=None,
                     help="hard-exit (os._exit 17) right after "
                          "printing step N's loss, before any save — "
@@ -128,11 +150,14 @@ def main():
         ap.error("--fault targets the single-host simulated trainer")
     if args.kill_at is not None and args.distributed:
         ap.error("--kill-at targets the single-host simulated trainer")
+    if args.profile_dir and args.distributed:
+        ap.error("--profile-dir targets the single-host simulated trainer")
     if (args.resume or args.save_every or args.fault) \
             and not args.ckpt_dir:
         ap.error("--resume/--save-every/--fault need --ckpt-dir")
 
     if not args.distributed:
+        from repro import tracing
         from repro.comm.faults import FaultPlan
         from repro.launch import runner
         from repro.training import simulated as sim
@@ -147,7 +172,10 @@ def main():
             keep=args.keep, resume=args.resume,
             max_retries=args.max_retries,
             fault_plan=FaultPlan.parse(args.fault),
-            kill_at=args.kill_at)
+            kill_at=args.kill_at,
+            profile=tracing.StepProfile(args.profile_dir,
+                                        *args.profile_steps)
+            if args.profile_dir else None)
         print(f"final loss {np.mean(losses[-5:]):.4f}")
         if args.checkpoint:
             ckpt.save(args.checkpoint, state["params"])

@@ -25,6 +25,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import moe as M
@@ -138,24 +139,27 @@ def _attn_ffn_layer(cfg: ModelConfig, lp, h, positions, window, *,
                     expert_map=None, moe_per_sequence=False,
                     moe_ep=None):
     """One dense/moe decoder layer.  Returns (h, new_cache, aux)."""
-    a, new_cache = L.attention(
-        lp["attn"], L.rmsnorm(lp["norm1"], h, cfg.norm_eps),
-        num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-        head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
-        positions=positions, window=window, attn_softcap=cfg.attn_softcap,
-        kv_cache=cache, cache_index=cache_index, block_k=block_k)
+    with jax.named_scope(tracing.ATTN):
+        a, new_cache = L.attention(
+            lp["attn"], L.rmsnorm(lp["norm1"], h, cfg.norm_eps),
+            num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+            head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
+            positions=positions, window=window,
+            attn_softcap=cfg.attn_softcap, kv_cache=cache,
+            cache_index=cache_index, block_k=block_k)
     h = h + a
-    hn = L.rmsnorm(lp["norm2"], h, cfg.norm_eps)
-    if "router" in lp.get("ffn", {}):
-        ep_axis, ep_size, ep_w = moe_ep if moe_ep else (None, 0, None)
-        f, aux = M.moe_ffn(lp["ffn"], hn, top_k=cfg.top_k,
-                           capacity_factor=cfg.capacity_factor,
-                           act=cfg.act, expert_map=expert_map,
-                           per_sequence=moe_per_sequence,
-                           ep_axis=ep_axis, ep_size=ep_size,
-                           ep_weights=ep_w)
-    else:
-        f, aux = L.mlp(lp["ffn"], hn, act=cfg.act), 0.0
+    with jax.named_scope(tracing.FFN):
+        hn = L.rmsnorm(lp["norm2"], h, cfg.norm_eps)
+        if "router" in lp.get("ffn", {}):
+            ep_axis, ep_size, ep_w = moe_ep if moe_ep else (None, 0, None)
+            f, aux = M.moe_ffn(lp["ffn"], hn, top_k=cfg.top_k,
+                               capacity_factor=cfg.capacity_factor,
+                               act=cfg.act, expert_map=expert_map,
+                               per_sequence=moe_per_sequence,
+                               ep_axis=ep_axis, ep_size=ep_size,
+                               ep_weights=ep_w)
+        else:
+            f, aux = L.mlp(lp["ffn"], hn, act=cfg.act), 0.0
     return h + f, new_cache, aux
 
 
@@ -174,6 +178,26 @@ def _scan_layers(step, h, stacked, xs_extra=None, remat=False):
     xs = (stacked,) if xs_extra is None else (stacked, *xs_extra)
     (h, aux), _ = jax.lax.scan(lambda c, x: (body(c, x), None), (h, 0.0), xs)
     return h, aux
+
+
+def _stage_slice(layers, sl: slice):
+    """One stage's layers of the stacked weights.  The slice of each
+    attention or FFN block's weights (its norm's included) runs under
+    that block's scope, so the slice, and the cast to the matmul's
+    precision that the compiler fuses into it, are timed with it."""
+    scopes = {}
+    if "attn" in layers:
+        scopes.update(norm1=tracing.ATTN, attn=tracing.ATTN)
+    if "ffn" in layers:
+        scopes.update(norm2=tracing.FFN, ffn=tracing.FFN)
+
+    def take(path, a):
+        scope = scopes.get(getattr(path[0], "key", None))
+        if scope is None:
+            return a[sl]
+        with jax.named_scope(scope):
+            return a[sl]
+    return jax.tree_util.tree_map_with_path(take, layers)
 
 
 def trunk_forward(params: Params, cfg: ModelConfig, h: jax.Array,
@@ -235,7 +259,7 @@ def trunk_forward(params: Params, cfg: ModelConfig, h: jax.Array,
 
         for s in range(num_stages):
             sl = slice(s * per_stage, (s + 1) * per_stage)
-            stacked = jax.tree.map(lambda a: a[sl], params["layers"])
+            stacked = _stage_slice(params["layers"], sl)
             if fam == "audio":
                 xs_extra = (windows[sl], xk_all[sl], xv_all[sl])
             else:
@@ -310,12 +334,14 @@ def encode_audio(params: Params, cfg: ModelConfig, frames: jax.Array,
 def embed_tokens(params, cfg: ModelConfig, tokens, extra_embeds=None):
     """tokens (..., S_text) -> (..., S, d); extra_embeds (patches/frames)
     are prepended along the sequence dim (pixtral stub)."""
-    h = params["embed"].astype(cfg.jax_dtype)[tokens]
+    with jax.named_scope(tracing.EMBED):
+        h = params["embed"].astype(cfg.jax_dtype)[tokens]
     if extra_embeds is not None:
         h = jnp.concatenate([extra_embeds.astype(h.dtype), h], axis=-2)
     return h
 
 
+@jax.named_scope(tracing.LM_HEAD)
 def lm_logits(params, cfg: ModelConfig, h):
     h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["head"]
@@ -323,6 +349,7 @@ def lm_logits(params, cfg: ModelConfig, h):
     return L.softcap(logits.astype(jnp.float32), cfg.final_softcap)
 
 
+@jax.named_scope(tracing.LM_HEAD)
 def cross_entropy(logits, targets, mask):
     """logits (B,S,V) fp32; targets (B,S) int; mask (B,S) {0,1}."""
     lse = jax.nn.logsumexp(logits, axis=-1)

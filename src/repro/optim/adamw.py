@@ -11,6 +11,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+
 
 @dataclass(frozen=True)
 class AdamWConfig:
@@ -79,6 +81,7 @@ def init_bucket_opt_state(n_ranks: int, seg: int, group_d: int) -> dict:
             "step": jnp.zeros((), jnp.int32)}
 
 
+@jax.named_scope(tracing.ADAMW)
 def apply_bucket_updates(cfg: AdamWConfig, pbucket, gbucket,
                          state) -> tuple[Any, dict]:
     """AdamW on the flattened (n, seg, group_d) parameter bucket —
@@ -113,6 +116,7 @@ def apply_bucket_updates(cfg: AdamWConfig, pbucket, gbucket,
     return new_p, {"mu": mu, "nu": nu, "step": step}
 
 
+@jax.named_scope(tracing.ADAMW)
 def apply_updates(cfg: AdamWConfig, params, grads, state) -> tuple[Any, dict]:
     step = state["step"] + 1
     lr = lr_at(cfg, step)

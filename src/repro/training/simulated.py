@@ -53,6 +53,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.comm import faults as faults_mod
 from repro.comm.config import CommConfig, reject_legacy_comm
 from repro.configs.base import ModelConfig
@@ -159,7 +160,9 @@ def train_step(state, batch, key, *, mcfg: ModelConfig,
     if cc.mode == "aqsgd":
         m_all = [aqsgd.read_buffer(cc, bufs, i, ids, mcfg.d_model)
                  for i in range(tcfg.num_stages - 1)]
-        seen_all = [bufs["seen"][i][ids] for i in range(tcfg.num_stages - 1)]
+        with jax.named_scope(tracing.STORE):
+            seen_all = [bufs["seen"][i][ids]
+                        for i in range(tcfg.num_stages - 1)]
     else:
         m_all = seen_all = None
 
