@@ -16,7 +16,6 @@ import functools
 import jax.numpy as jnp
 
 from repro.kernels import quant_pack as _qp
-from repro.kernels import flash_attention as _fa
 
 
 @functools.lru_cache(maxsize=1)
@@ -199,7 +198,3 @@ def dequant_sum_mean(total, s, *, bits: int, n: int, block_r: int = 128):
     out = _qp.dequant_sum_mean(t2, s2, bits=bits, n=n, block_r=block_r)
     return out[:r].reshape(shape)
 
-
-def flash_attention(q, k, v, **kw):
-    """(B, H, Sq, hd) x (B, Hk, Sk, hd) -> (B, H, Sq, hd)."""
-    return _fa.flash_attention_fwd(q, k, v, **kw)

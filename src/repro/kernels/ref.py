@@ -105,22 +105,3 @@ def dequant_sum_mean_ref(total, s, bits: int, n: int):
     """Int32 code sum over n workers + shared scale -> mean gradient."""
     return Q.dequantize_sum_mean(total, s, bits, n)
 
-
-def flash_attention_ref(q, k, v, *, causal=True, window=10 ** 9,
-                        softcap=0.0):
-    """Dense attention oracle.  q,k,v: (B, H, S, hd) (head-major)."""
-    b, h, s, hd = q.shape
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    if softcap > 0:
-        logits = softcap * jnp.tanh(logits / softcap)
-    pos = jnp.arange(s)
-    vis = jnp.ones((s, s), bool)
-    if causal:
-        vis &= pos[None, :] <= pos[:, None]
-    vis &= pos[None, :] > pos[:, None] - window
-    logits = jnp.where(vis, logits, -1e9)
-    p = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p,
-                      v.astype(jnp.float32)).astype(q.dtype)

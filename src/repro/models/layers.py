@@ -8,11 +8,14 @@ Design constraints that shape this file:
   scalar), never as structural differences.
 * **Blockwise attention** — prefill_32k would need O(S²) score
   materialization with naive attention (TBs at full scale); we use an
-  online-softmax blockwise formulation (lax.scan over KV blocks) so the
-  full-scale dry-runs fit HBM.  Decode (S_q = 1) uses single-shot scores.
+  online-softmax blockwise formulation so the full-scale dry-runs fit
+  HBM: on a TPU the Pallas flash kernels (`kernels/flash_attention.py`),
+  elsewhere a lax.scan over KV blocks.  Decode (S_q = 1) uses
+  single-shot scores.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Optional
@@ -20,6 +23,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from repro import env
+from repro.kernels import flash_attention as FA
 
 NEG_INF = -1.0e9
 
@@ -270,17 +277,62 @@ def _make_flash(causal: bool, cap: float, block_k: int):
     return flash
 
 
+# (mesh, batch axes) of the GSPMD sections being traced (`rows_over`)
+_ROWS: list = []
+
+
+@contextlib.contextmanager
+def rows_over(mesh, axes):
+    """Trace attention inside as batch-sharded over ``axes`` of ``mesh``.
+
+    GSPMD cannot partition a Mosaic call, so in a jit over several
+    devices the Pallas kernels run under a shard_map that hands each
+    device its own rows: attention is per row, nothing crosses devices.
+    Axes of the mesh not in ``axes`` see the rows replicated, as GSPMD
+    would hold them.  A call already inside a shard_map, and the scan,
+    which GSPMD partitions itself, ignore this."""
+    _ROWS.append((mesh, axes))
+    try:
+        yield
+    finally:
+        _ROWS.pop()
+
+
+def _kernel_attention(q, k, v, q_pos, k_pos, window, **statics):
+    """`FA.flash_attention`, per shard of rows under `rows_over`."""
+    fn = functools.partial(FA.flash_attention, **statics)
+    if not _ROWS or jax.sharding.get_abstract_mesh().manual_axes:
+        return fn(q, k, v, q_pos, k_pos, window)
+    mesh, axes = _ROWS[-1]
+    rows = P(axes)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(rows,) * 5 + (P(),),
+                         out_specs=rows, check_vma=False)(
+        q, k, v, q_pos, k_pos, window)
+
+
 def flash_attention(q, k, v, *, q_pos, k_pos, window, causal=True,
                     attn_softcap=0.0, block_k=512, block_q=2048):
     """Memory-lean attention used on all training/prefill paths.
+    q: (B, Sq, H, hd); k, v: (B, Sk, Hk, hd) with Hk dividing H.
     window may be a traced per-layer scalar (scan homogeneity).
 
-    Q is chunked with lax.map when Sq > block_q: without it a 32k prefill
-    materializes (B, H, Sq, block_k) f32 score tiles (~13 GB on mixtral).
+    On a TPU, shapes the Pallas kernels tile
+    (`kernels.flash_attention.block_q_for`) run there, forward and
+    backward (per shard of rows inside `rows_over`); elsewhere, and for
+    other shapes, the `lax.scan` flash below runs, with the kv heads
+    repeated.  Its Q is chunked with lax.map when Sq > block_q: without
+    it a 32k prefill materializes (B, H, Sq, block_k) f32 score tiles
+    (~13 GB on mixtral).
     """
-    fn = _make_flash(bool(causal), float(attn_softcap), int(block_k))
     w = jnp.asarray(window, jnp.int32)
     sq = q.shape[1]
+    if not env.pallas_interpret() and FA.block_q_for(
+            sq, block_k, q.shape[-1]) is not None:
+        return _kernel_attention(q, k, v, q_pos, k_pos, w, causal=causal,
+                                 softcap=attn_softcap, block_k=block_k)
+    groups = q.shape[2] // k.shape[2]
+    k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
+    fn = _make_flash(bool(causal), float(attn_softcap), int(block_k))
     if sq <= block_q or sq % block_q:
         return fn(q, k, v, q_pos, k_pos, w)
     nq = sq // block_q
@@ -367,10 +419,6 @@ def attention(p, x, *, num_heads, num_kv_heads, head_dim, rope_theta,
                                window=window, causal=causal,
                                attn_softcap=attn_softcap)
     else:
-        if cross_kv is None:
-            groups = num_heads // num_kv_heads
-            k = _repeat_kv(k, groups)
-            v = _repeat_kv(v, groups)
         out = flash_attention(q, k, v, q_pos=positions, k_pos=k_pos,
                               window=window, causal=causal,
                               attn_softcap=attn_softcap, block_k=block_k)

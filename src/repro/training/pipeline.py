@@ -991,19 +991,23 @@ def make_train_step(cfg: ModelConfig, pcfg: PipelineConfig, mesh,
             seq = h.shape[2]
             positions = jnp.broadcast_to(
                 jnp.arange(seq, dtype=jnp.int32), h.shape[1:3])
-            for i, lp in enumerate(params.get("prefix", [])):
-                w = cfg.layer_window(i, seq)
-                h = jax.vmap(lambda hh: Mo._attn_ffn_layer(
-                    cfg, lp, hh, positions, w, block_k=pcfg.block_k)[0])(h)
+            # the prefix layers and the encoder run here, under GSPMD:
+            # their attention kernels run per data shard of the rows
+            with L.rows_over(mesh, d_ax):
+                for i, lp in enumerate(params.get("prefix", [])):
+                    w = cfg.layer_window(i, seq)
+                    h = jax.vmap(lambda hh: Mo._attn_ffn_layer(
+                        cfg, lp, hh, positions, w,
+                        block_k=pcfg.block_k)[0])(h)
+                if cfg.family == "audio":
+                    enc = jax.vmap(lambda fr: Mo.encode_audio(
+                        params, cfg, fr, remat=pcfg.remat,
+                        block_k=pcfg.block_k))(batch["frames"])
+                    extra_all = enc.astype(cfg.jax_dtype)
+                else:
+                    extra_all = jnp.zeros((M, 1, 1, 1), cfg.jax_dtype)
             h_all = h
             ids = batch["sample_ids"]             # (M, Bmb)
-            if cfg.family == "audio":
-                enc = jax.vmap(lambda fr: Mo.encode_audio(
-                    params, cfg, fr, remat=pcfg.remat,
-                    block_k=pcfg.block_k))(batch["frames"])
-                extra_all = enc.astype(cfg.jax_dtype)
-            else:
-                extra_all = jnp.zeros((M, 1, 1, 1), cfg.jax_dtype)
             shared = params.get("shared_block", {})
             if has_bufs:
                 m_out, m_in = state["m_out"], state["m_in"]

@@ -25,7 +25,7 @@ def run_worker(script, arg, timeout=1500):
 @pytest.mark.parametrize("check", [
     "fp32_equivalence", "aqsgd_buffers", "zbit_buffers",
     "modes_all_archs", "expert_parallel", "dp_grad_pipeline",
-    "dp_wire_parity", "dp_wire_fp16"])
+    "dp_wire_parity", "dp_wire_fp16", "kernels_under_rows_over"])
 def test_pipeline(check):
     out = run_worker("pipeline_worker.py", check)
     assert f"OK {check}" in out or "OK" in out

@@ -7,8 +7,12 @@ VMEM are only checked by the TPU compiler.  These tests compile each
 described (not attached) ``v5e:2x2`` topology at gpt2-xl width
 (d = 1600, packed widths 400 / 800 / 1600 — not multiples of 128) and
 at the DP bucket width (group_d = 512), bits 2 / 4 / 8, plus the ragged
-small-row grid `ops._padded_rows` produces.  Nothing runs; a compile
-that passes is not a chip run.
+small-row grid `ops._padded_rows` produces; and the three flash
+attention kernels (`repro.kernels.flash_attention`) at the gpt2-xl
+cell's shapes and at a GQA shape, and pipeline train steps over all
+four chips whose dense prefix layer or encoder takes those kernels
+outside the trunk's shard_map.  Nothing runs; a compile that passes is
+not a chip run.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, so describing it while
@@ -18,12 +22,20 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
+from repro import env
+from repro.comm.config import CommConfig
+from repro.configs.base import get_config
 from repro.core import quantization as Q
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops
 from repro.kernels import quant_pack as qp
+from repro.optim.adamw import AdamWConfig
+from repro.training import pipeline as PL
 
 ROWS = 4096          # batch x seq of one microbatch (4 x 1024)
 N_WORKERS = 4        # DP ring size: code sums packed at 4 / 8 / 16 bits
@@ -113,3 +125,75 @@ def test_oncore_prng_encode_compiles_for_v5e(one_chip, name):
     compiled = fn.lower(*args, bits=4, seed=seed, interpret=False,
                         **kw).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+# (B, H, Hk, S, hd, dtype, softcap): the gpt2-xl cell (batch 4 x 1024,
+# 25 heads of 64, f32) and gemma2-9b's attention (GQA 16/8, hd 256,
+# bf16, softcap 50)
+FLASH_SHAPES = {
+    "gpt2xl": (4, 25, 25, 1024, 64, jnp.float32, 0.0),
+    "gqa": (1, 16, 8, 1024, 256, jnp.bfloat16, 50.0),
+}
+FLASH_BLOCK_K = 512
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dkv",
+                                    "flash_bwd_dq"])
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
+def test_flash_kernel_compiles_for_v5e(one_chip, shape, kernel):
+    b, h, hk, s, hd, dtype, cap = FLASH_SHAPES[shape]
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q, kv = arg((b, h, hd, s), dtype), arg((b, hk, hd, s), dtype)
+    pos, window = arg((b, s), jnp.int32), arg((), jnp.int32)
+    block_q = fa.block_q_for(s, FLASH_BLOCK_K, hd)
+    assert block_q is not None
+    st = dict(causal=True, softcap=cap, block_q=block_q,
+              block_k=FLASH_BLOCK_K, interpret=False)
+    if kernel == "flash_fwd":
+        fn = jax.jit(lambda *a: fa.forward(*a, **st))
+        args = (q, kv, kv, pos, pos, window)
+    else:
+        fn = jax.jit(lambda *a: fa.backward(*a, **st))
+        args = (q, kv, kv, pos, pos, window, q,
+                arg((b, h, 1, s), jnp.float32), q)
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and kernel in text, kernel
+
+
+# arch -> what of it the pipeline runs in its GSPMD section, at a
+# sequence of 128 the kernels tile
+GSPMD_ATTENTION = {
+    "deepseek-moe-16b": dict(),             # its dense first layer
+    "whisper-small": dict(encoder_seq=128),  # the audio encoder
+}
+
+
+@pytest.mark.parametrize("arch", sorted(GSPMD_ATTENTION))
+def test_pipeline_gspmd_attention_compiles_for_v5e_2x2(topo, monkeypatch,
+                                                       arch):
+    """deepseek-moe's dense first layer and whisper's encoder run in the
+    pipeline step's GSPMD section, outside the trunk's shard_map, over
+    all four chips of a (data 2, model 2) mesh.  At a sequence the
+    kernels tile, their attention must run them per data shard
+    (`layers.rows_over`): GSPMD refuses to partition a Mosaic call."""
+    monkeypatch.setattr(env, "pallas_interpret", lambda: False)
+    cfg = get_config(arch, smoke=True).with_(**GSPMD_ATTENTION[arch])
+    pcfg = PL.PipelineConfig(microbatches=2, comm=CommConfig())
+    gb, seq = 4, 128
+    assert cfg.first_dense_layers or cfg.family == "audio"
+    assert fa.block_q_for(seq, pcfg.block_k, cfg.head_dim) is not None
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    step, meta = PL.make_train_step(cfg, pcfg, mesh, AdamWConfig(),
+                                    global_batch=gb, seq_len=seq,
+                                    buffer_samples=gb // 2)
+    state, batch, key = PL.make_state_structs(cfg, pcfg, meta, mesh,
+                                              global_batch=gb,
+                                              seq_len=seq)
+    key = jax.ShapeDtypeStruct(key.shape, key.dtype,
+                               sharding=NamedSharding(mesh, P()))
+    text = step.lower(state, batch, key).compile().as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert kernel in text, kernel

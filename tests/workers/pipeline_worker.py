@@ -7,6 +7,8 @@ Checks:
                      compressed steps then train with finite losses and a
                      shrinking delta magnitude
   modes_all_archs  — one pipeline step for dense/moe/ssm/hybrid/audio/vlm
+  kernels_under_rows_over — the flash kernels (interpret mode) in the
+                     GSPMD section, per data shard: losses == the scan's
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -28,8 +30,8 @@ from repro.training import pipeline as PL
 
 def build(arch, mode, *, num_layers=None, warmup=False, M=2, Bg=4, S=32,
           lr=0.0, buffer_bits=0, dp_grad_bits=0, dp_wire="ring",
-          dp_chunks=1):
-    cfg = get_config(arch, smoke=True)
+          dp_chunks=1, **overrides):
+    cfg = get_config(arch, smoke=True).with_(**overrides)
     if num_layers:
         cfg = cfg.with_(num_layers=num_layers)
     mesh = make_debug_mesh(2, 2)
@@ -297,6 +299,47 @@ def check_expert_parallel():
     print("zero3", l_z3, "ep", l_ep)
     np.testing.assert_allclose(l_ep, l_z3, rtol=1e-4)
     print("OK expert_parallel")
+
+
+def check_kernels_under_rows_over():
+    """deepseek-moe's dense prefix layer and whisper's encoder run in the
+    train step's GSPMD section, where the flash kernels run per data
+    shard under `layers.rows_over` (on a TPU: GSPMD cannot partition a
+    Mosaic call).  Routed to the kernels here, in interpret mode, three
+    training steps on the (2, 2) mesh give the scan's losses: the
+    shard_map's gradient over the replicated model axis is summed once,
+    not once per model rank."""
+    import types
+    from repro.models import layers as L
+    real_env, real_attention = L.env, L._kernel_attention
+    for arch, kw in [("deepseek-moe-16b", {}),
+                     ("whisper-small", {"encoder_seq": 128})]:
+        losses = {}
+        for path in ("kernels", "scan"):
+            sharded = []
+
+            def spy(*a, **k):
+                sharded.append(bool(L._ROWS) and not
+                               jax.sharding.get_abstract_mesh().manual_axes)
+                return real_attention(*a, **k)
+
+            L.env = types.SimpleNamespace(
+                pallas_interpret=lambda: path == "scan")
+            L._kernel_attention = spy
+            try:
+                cfg, step, state, batch = build(arch, "aqsgd", warmup=True,
+                                                lr=1e-3, S=128, **kw)
+                losses[path] = []
+                for i in range(3):
+                    state, m = step(state, batch, jax.random.PRNGKey(3 + i))
+                    losses[path].append(float(m["loss"]))
+            finally:
+                L.env, L._kernel_attention = real_env, real_attention
+            assert any(sharded) == (path == "kernels"), (arch, path, sharded)
+        print(arch, losses)
+        np.testing.assert_allclose(losses["kernels"], losses["scan"],
+                                   rtol=1e-5)
+    print("OK kernels_under_rows_over")
 
 
 if __name__ == "__main__":
