@@ -1,7 +1,10 @@
 """mamba2-1.3b [ssm] — SSD (state-space duality), attention-free.
 
-[arXiv:2405.21060].  48L, d_model=2048, ssm_state=128, headdim=64,
-expand=2, vocab=50280.  No FFN — the Mamba2 block is the whole layer.
+Sources: arXiv:2405.21060 and https://huggingface.co/state-spaces/mamba2-1.3b
+(48L, d_model=2048, ssm_state=128, headdim=64, expand=2, ngroups=1,
+conv width 4, chunk 256, RMSNorm eps 1e-5 in the layer norm and the
+gated norm, tied embeddings).  vocab=50280: the tokenizer's 50,277 ids
+padded.  No FFN — the Mamba2 block is the whole layer.
 """
 from repro.configs.base import ModelConfig
 
@@ -21,6 +24,7 @@ CONFIG = ModelConfig(
     ssm_expand=2,
     ssm_conv_width=4,
     ssm_chunk=256,
+    norm_eps=1e-5,
 )
 
 SMOKE = CONFIG.with_(

@@ -164,8 +164,9 @@ def _attn_ffn_layer(cfg: ModelConfig, lp, h, positions, window, *,
 
 
 def _mamba_layer(cfg: ModelConfig, lp, h):
-    out, _ = S.mamba2_forward(
-        lp["mamba"], L.rmsnorm(lp["norm1"], h, cfg.norm_eps), cfg)
+    with jax.named_scope(tracing.SSM):
+        out, _ = S.mamba2_forward(
+            lp["mamba"], L.rmsnorm(lp["norm1"], h, cfg.norm_eps), cfg)
     return h + out
 
 
@@ -182,12 +183,15 @@ def _scan_layers(step, h, stacked, xs_extra=None, remat=False):
 
 def _stage_slice(layers, sl: slice):
     """One stage's layers of the stacked weights.  The slice of each
-    attention or FFN block's weights (its norm's included) runs under
-    that block's scope, so the slice, and the cast to the matmul's
-    precision that the compiler fuses into it, are timed with it."""
+    attention, FFN or Mamba-2 block's weights (its norm's included)
+    runs under that block's scope, so the slice, and the cast to the
+    matmul's precision that the compiler fuses into it, are timed with
+    it."""
     scopes = {}
     if "attn" in layers:
         scopes.update(norm1=tracing.ATTN, attn=tracing.ATTN)
+    elif "mamba" in layers:
+        scopes.update(norm1=tracing.SSM, mamba=tracing.SSM)
     if "ffn" in layers:
         scopes.update(norm2=tracing.FFN, ffn=tracing.FFN)
 

@@ -19,6 +19,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.models.layers import rmsnorm
 
 
@@ -143,8 +144,9 @@ def mamba2_forward(p, x, cfg, initial_state=None):
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
                          + p["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    y, final_state = ssd_chunked(xh, dt, A, Bc, Cc, cfg.ssm_chunk,
-                                 initial_state)
+    with jax.named_scope(tracing.SSD):
+        y, final_state = ssd_chunked(xh, dt, A, Bc, Cc, cfg.ssm_chunk,
+                                     initial_state)
     y = y + xh.astype(jnp.float32) * p["D"].astype(jnp.float32)[:, None]
     y = y.reshape(b, l, di).astype(dtype)
     y = rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)

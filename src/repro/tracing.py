@@ -10,6 +10,12 @@ backward ``transpose(jvp(attn))``, remat's recompute under
 to a layer by instruction name.  A scope changes only metadata: the
 compiled program is the same.
 
+`SSM_SCOPES` name the Mamba-2 layers of the ``ssm`` and ``hybrid``
+families: ``ssm`` around the whole layer, ``ssd`` around its SSD scan
+inside it.  They stand apart from `SCOPES`, which every train step
+carries (the dense families run no Mamba-2 layer), and which the
+benchmark's scope readers copy name for name.
+
 Host side.  `span` is a `jax.profiler.TraceAnnotation` named
 ``repro.<name>``: it costs nothing unless a profile is being taken,
 and then lies on the same clock as the device trace.  `compile_counts`
@@ -34,6 +40,9 @@ BOUNDARY = "boundary"    # AQ-SGD / DirectQ codec at a stage boundary
 STORE = "store"          # per-sample gather/scatter of the message store
 ADAMW = "adamw"          # the optimizer update
 SCOPES = (ATTN, FFN, EMBED, LM_HEAD, BOUNDARY, STORE, ADAMW)
+SSM = "ssm"              # a Mamba-2 layer: projections, conv, gated norm
+SSD = "ssd"              # its chunked SSD scan
+SSM_SCOPES = (SSM, SSD)
 
 SPAN_PREFIX = "repro."
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"

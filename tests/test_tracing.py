@@ -23,6 +23,7 @@ from repro.training import simulated as sim
 # what the per-layer metrics see
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "bench"))
+from harness import ssm_scopes  # noqa: E402
 from harness.scopes import classify  # noqa: E402
 
 SAMPLES, SEQ, BATCH = 16, 32, 4
@@ -30,8 +31,8 @@ OP_NAME = re.compile(r'op_name="([^"]*)"')
 MODEL_SCOPES = (tracing.ATTN, tracing.FFN, tracing.LM_HEAD)
 
 
-def _configs(remat: bool = True):
-    cfg = get_config("gpt2-xl-paper", smoke=True)
+def _configs(remat: bool = True, arch: str = "gpt2-xl-paper"):
+    cfg = get_config(arch, smoke=True)
     comm = CommConfig.from_dict({"mode": "aqsgd", "fw": {"bits": 4},
                                  "bw": {"bits": 8}})
     tcfg = sim.SimTrainConfig(num_stages=2, comm=comm, remat=remat,
@@ -41,18 +42,29 @@ def _configs(remat: bool = True):
     return cfg, tcfg
 
 
-@pytest.fixture(scope="module")
-def step_hlo():
-    cfg, tcfg = _configs()
+def _step_hlo(arch: str, seq: int = SEQ) -> str:
+    cfg, tcfg = _configs(arch=arch)
     state = jax.eval_shape(lambda: sim.init_train_state(
-        cfg, tcfg, SAMPLES, SEQ, jax.random.PRNGKey(0)))
-    i32 = jax.ShapeDtypeStruct((BATCH, SEQ), jnp.int32)
+        cfg, tcfg, SAMPLES, seq, jax.random.PRNGKey(0)))
+    i32 = jax.ShapeDtypeStruct((BATCH, seq), jnp.int32)
     batch = {"tokens": i32, "targets": i32,
-             "mask": jax.ShapeDtypeStruct((BATCH, SEQ), jnp.float32),
+             "mask": jax.ShapeDtypeStruct((BATCH, seq), jnp.float32),
              "sample_ids": jax.ShapeDtypeStruct((BATCH,), jnp.int32)}
     return sim.train_step.lower(
         state, batch, jax.random.PRNGKey(1), mcfg=cfg,
         tcfg=tcfg).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def step_hlo():
+    return _step_hlo("gpt2-xl-paper")
+
+
+@pytest.fixture(scope="module")
+def mamba_step_hlo():
+    # two of the smoke config's chunks of 32, so that the SSD contracts
+    # across chunks
+    return _step_hlo("mamba2-1.3b", seq=2 * SEQ)
 
 
 def test_step_carries_every_scope_in_its_phases(step_hlo):
@@ -72,6 +84,43 @@ def test_stage_slices_are_timed_with_their_block(step_hlo):
     for scope in (tracing.ATTN, tracing.FFN):
         assert f"jit(train_step)/jvp({scope})/slice" in paths, scope
     assert "jit(train_step)/jvp()/slice" not in paths
+
+
+def _dots(text: str) -> list:
+    """(ssm scope, (scope, phase)) of every dot of the HLO text."""
+    out = []
+    for line in text.splitlines():
+        m = OP_NAME.search(line)
+        if " dot(" in line and m:
+            out.append((ssm_scopes.classify(m.group(1)),
+                        classify(m.group(1))))
+    return out
+
+
+def test_mamba_step_puts_its_dots_under_ssd_and_ssm(mamba_step_hlo):
+    """The SSD's contractions run under ``ssd`` and the in and out
+    projections under ``ssm`` outside it, in the forward pass, the
+    backward pass and remat's recompute; every other dot is the
+    head's."""
+    dots = _dots(mamba_step_hlo)
+    for scope in tracing.SSM_SCOPES:
+        for phase in ("forward", "backward", "recompute"):
+            assert any(s == scope and p == phase
+                       for s, (_, p) in dots), (scope, phase)
+    assert all(s is not None or outer == tracing.LM_HEAD
+               for s, (outer, _) in dots), dots
+    # the stage slices of the stacked weights run under ``ssm``
+    paths = set(OP_NAME.findall(mamba_step_hlo))
+    assert f"jit(train_step)/jvp({tracing.SSM})/slice" in paths
+    assert "jit(train_step)/jvp()/slice" not in paths
+
+
+def test_gpt2_step_carries_no_ssm_scope(step_hlo):
+    """The scopes of the Mamba-2 layers leave the attention model's
+    step as it was: no instruction of it lies under either."""
+    assert ssm_scopes.table(step_hlo) == {}
+    assert not any(ssm_scopes.classify(p)
+                   for p in OP_NAME.findall(step_hlo))
 
 
 def _outside_dots_dropped(text: str) -> str:
