@@ -32,6 +32,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import tracing
 from repro.core import boundary as B
@@ -102,34 +103,57 @@ def buffer_nbytes(cc: CompressionConfig, num_boundaries: int,
     return nb * num_samples * seq * d * jnp.dtype(cc.buffer_dtype).itemsize
 
 
+def _rows(x: jax.Array, sample_ids: jax.Array) -> jax.Array:
+    """``x[:, sample_ids]``, one dynamic slice a sample, so that only
+    those rows are read: on the TPU, XLA lowers that gather to slices
+    of the whole store."""
+    return jnp.concatenate([
+        lax.dynamic_slice_in_dim(x, sample_ids[j], 1, axis=1)
+        for j in range(sample_ids.shape[0])], axis=1)
+
+
+def _set_rows(x: jax.Array, sample_ids: jax.Array,
+              rows: jax.Array) -> jax.Array:
+    """``x.at[:, sample_ids].set(rows)``, one dynamic update a sample,
+    each in place and in the store's own layout."""
+    rows = rows.astype(x.dtype)
+    for j in range(sample_ids.shape[0]):
+        x = lax.dynamic_update_slice_in_dim(x, rows[:, j:j + 1],
+                                            sample_ids[j], axis=1)
+    return x
+
+
 @jax.named_scope(tracing.STORE)
-def read_buffer(cc: CompressionConfig, bufs: dict, boundary: int,
-                sample_ids: jax.Array, d: int) -> jax.Array:
-    """-> m (B, S, d) float32 for the given samples."""
+def read_buffers(cc: CompressionConfig, bufs: dict, sample_ids: jax.Array,
+                 d: int) -> tuple:
+    """-> (m (nb, B, S, d) float32, seen (nb, B)): every boundary's
+    messages and first-visit flags for the given samples."""
     if cc.buffer_bits:
-        codes = bufs["codes"][boundary][sample_ids]
-        scale = bufs["scale"][boundary][sample_ids]
-        return B.decode(codes, scale, bits=cc.buffer_bits, d=d,
-                        backend=cc.backend)
-    return bufs["m"][boundary][sample_ids].astype(jnp.float32)
+        m = B.decode(_rows(bufs["codes"], sample_ids),
+                     _rows(bufs["scale"], sample_ids),
+                     bits=cc.buffer_bits, d=d, backend=cc.backend)
+    else:
+        m = _rows(bufs["m"], sample_ids).astype(jnp.float32)
+    return m, _rows(bufs["seen"], sample_ids)
 
 
 @jax.named_scope(tracing.STORE)
-def write_buffer(cc: CompressionConfig, bufs: dict, boundary: int,
-                 sample_ids: jax.Array, m_new: jax.Array) -> dict:
-    """Store the updated messages for `sample_ids` at one boundary
-    (raw dtype, or z-bit codes + scales when ``cc.buffer_bits``) and
-    mark them seen — the write half of Algorithm 2's buffer state."""
+def write_buffers(cc: CompressionConfig, bufs: dict, sample_ids: jax.Array,
+                  m_new: jax.Array) -> dict:
+    """Store the updated messages m_new (nb, B, S, d) for `sample_ids`
+    at every boundary (raw dtype, or z-bit codes + scales when
+    ``cc.buffer_bits``) and mark them seen — the write half of
+    Algorithm 2's buffer state."""
     bufs = dict(bufs)
     if cc.buffer_bits:
         packed, scale = B.encode(m_new, bits=cc.buffer_bits,
                                  stochastic=False, backend=cc.backend)
-        bufs["codes"] = bufs["codes"].at[boundary, sample_ids].set(packed)
-        bufs["scale"] = bufs["scale"].at[boundary, sample_ids].set(scale)
+        bufs["codes"] = _set_rows(bufs["codes"], sample_ids, packed)
+        bufs["scale"] = _set_rows(bufs["scale"], sample_ids, scale)
     else:
-        bufs["m"] = bufs["m"].at[boundary, sample_ids].set(
-            m_new.astype(bufs["m"].dtype))
-    bufs["seen"] = bufs["seen"].at[boundary, sample_ids].set(True)
+        bufs["m"] = _set_rows(bufs["m"], sample_ids, m_new)
+    bufs["seen"] = _set_rows(bufs["seen"], sample_ids,
+                             jnp.ones(m_new.shape[:2], bool))
     return bufs
 
 

@@ -13,7 +13,7 @@ This is the engine behind the paper-validation benchmarks (Fig. 1a/3/5/9).
 
 The boundary codec backend (fused Pallas kernels vs reference jnp chain)
 is selected by ``CompressionConfig.backend`` and flows through
-``apply_boundary``/``read_buffer``/``write_buffer`` unchanged.  The two
+``apply_boundary``/``read_buffers``/``write_buffers`` unchanged.  The two
 backends are bit-identical per op (see core.boundary), so convergence
 results measured here transfer across backends up to the usual
 compiler-fusion ulp noise in the surrounding model compute.
@@ -53,7 +53,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import tracing
 from repro.comm import faults as faults_mod
 from repro.comm.config import CommConfig, reject_legacy_comm
 from repro.configs.base import ModelConfig
@@ -147,10 +146,15 @@ def _loss_with_boundaries(params, mcfg, tcfg, batch, m_all, seen_all, key):
     return loss, metrics
 
 
-@functools.partial(jax.jit, static_argnames=("mcfg", "tcfg"))
+@functools.partial(jax.jit, static_argnames=("mcfg", "tcfg"),
+                   donate_argnames=("state",))
 def train_step(state, batch, key, *, mcfg: ModelConfig,
                tcfg: SimTrainConfig):
-    """One AQ-SGD training step.  batch must include sample_ids."""
+    """One AQ-SGD training step.  batch must include sample_ids.
+
+    ``state`` is donated: the step writes the new state into its
+    buffers (the message store in place), and the caller's ``state``
+    is deleted, so rebind it to the result."""
     cc = tcfg.comm.activation
     dpc = tcfg.comm.dp
     dp_spec = tcfg.comm.dp_wire_spec if dpc.bits else None
@@ -158,11 +162,7 @@ def train_step(state, batch, key, *, mcfg: ModelConfig,
     bufs = state["buffers"]
     ids = batch["sample_ids"]
     if cc.mode == "aqsgd":
-        m_all = [aqsgd.read_buffer(cc, bufs, i, ids, mcfg.d_model)
-                 for i in range(tcfg.num_stages - 1)]
-        with jax.named_scope(tracing.STORE):
-            seen_all = [bufs["seen"][i][ids]
-                        for i in range(tcfg.num_stages - 1)]
+        m_all, seen_all = aqsgd.read_buffers(cc, bufs, ids, mcfg.d_model)
     else:
         m_all = seen_all = None
 
@@ -183,10 +183,10 @@ def train_step(state, batch, key, *, mcfg: ModelConfig,
         new_ms_parts, ce = [], 0.0
         for i in range(w):
             sub = {k: v[i * b:(i + 1) * b] for k, v in batch.items()}
-            sub_m = [m[:, i * b:(i + 1) * b] if m.ndim > 3 else
-                     m[i * b:(i + 1) * b] for m in m_all] if m_all else None
-            sub_s = [s[i * b:(i + 1) * b] for s in seen_all] \
-                if seen_all else None
+            sub_m = m_all[:, i * b:(i + 1) * b] \
+                if m_all is not None else None
+            sub_s = seen_all[:, i * b:(i + 1) * b] \
+                if seen_all is not None else None
             (l, met), g = jax.value_and_grad(
                 lambda p: _loss_with_boundaries(
                     p, mcfg, tcfg, sub, sub_m, sub_s,
@@ -265,9 +265,8 @@ def train_step(state, batch, key, *, mcfg: ModelConfig,
             tcfg.optimizer, state["params"], grads, state["opt"])
 
     if cc.mode == "aqsgd":
-        new_ms = metrics.pop("boundary_state")
-        for i, m_new in enumerate(new_ms):
-            bufs = aqsgd.write_buffer(cc, bufs, i, ids, m_new)
+        bufs = aqsgd.write_buffers(cc, bufs, ids,
+                                   jnp.stack(metrics.pop("boundary_state")))
     else:
         metrics.pop("boundary_state", None)
 
@@ -289,7 +288,8 @@ def train(mcfg: ModelConfig, tcfg: SimTrainConfig, dataset, *,
     state = init_train_state(mcfg, tcfg, dataset.num_samples,
                              dataset.dc.seq_len, k_init)
     if initial_params is not None:
-        state["params"] = initial_params
+        # a copy: the donating step would delete the caller's arrays
+        state["params"] = jax.tree.map(jnp.copy, initial_params)
     losses = []
     for step, batch in enumerate(dataset.batches(batch_size, num_steps)):
         batch = {k: jnp.asarray(v) for k, v in batch.items()}

@@ -105,14 +105,18 @@ def test_buffer_roundtrip(buffer_bits):
     cc = CompressionConfig(mode="aqsgd", buffer_bits=buffer_bits)
     bufs = aqsgd.init_buffers(cc, 2, 10, 8, 16)
     ids = jnp.array([3, 7], jnp.int32)
-    m = jax.random.normal(KEY, (2, 8, 16))
-    bufs = aqsgd.write_buffer(cc, bufs, 1, ids, m)
-    got = aqsgd.read_buffer(cc, bufs, 1, ids, 16)
+    m = jax.random.normal(KEY, (2, 2, 8, 16))
+    bufs = aqsgd.write_buffers(cc, bufs, ids, m)
+    got, seen = aqsgd.read_buffers(cc, bufs, ids, 16)
     tol = 1e-6 if buffer_bits == 0 else \
         float(jnp.max(jnp.abs(m))) * 2.0 / ((1 << buffer_bits) - 1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(m), atol=tol)
-    assert bool(bufs["seen"][1, 3]) and bool(bufs["seen"][1, 7])
-    assert not bool(bufs["seen"][0, 3])
+    assert np.asarray(seen).all()
+    assert bool(bufs["seen"][1, 3]) and bool(bufs["seen"][0, 7])
+    assert not bool(bufs["seen"][0, 4])
+    # the other samples' rows are left as they were
+    _, rest = aqsgd.read_buffers(cc, bufs, jnp.array([0, 9], jnp.int32), 16)
+    assert not np.asarray(rest).any()
 
 
 def test_buffer_nbytes_matches_paper_scale():

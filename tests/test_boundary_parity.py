@@ -141,16 +141,16 @@ def test_buffer_codec_bit_identical(buffer_bits):
     unpack_dequant kernels must reproduce the reference buffer codec
     exactly through a write→read cycle."""
     ids = jnp.array([3, 7], jnp.int32)
-    m = jax.random.normal(KEY, (2, 8, 128))
+    m = jax.random.normal(KEY, (2, 2, 8, 128))
 
     @functools.partial(jax.jit, static_argnames=("backend",))
     def cycle(m, *, backend):
         cc = CompressionConfig(mode="aqsgd", buffer_bits=buffer_bits,
                                backend=backend)
         bufs = aqsgd.init_buffers(cc, 2, 10, 8, 128)
-        bufs = aqsgd.write_buffer(cc, bufs, 1, ids, m)
+        bufs = aqsgd.write_buffers(cc, bufs, ids, m)
         return bufs["codes"], bufs["scale"], \
-            aqsgd.read_buffer(cc, bufs, 1, ids, 128)
+            aqsgd.read_buffers(cc, bufs, ids, 128)[0]
 
     c_r, s_r, out_r = cycle(m, backend="reference")
     c_p, s_p, out_p = cycle(m, backend="pallas")

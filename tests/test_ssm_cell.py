@@ -10,6 +10,7 @@ import sys
 import time
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
@@ -47,7 +48,9 @@ def _run():
 
 def _unchanged(real):
     def step(state, batch, key, **kw):
-        return state, real(state, batch, key, **kw)[1]
+        # the real step donates what it is handed: hand it a copy
+        return state, real(jax.tree.map(jnp.copy, state), batch, key,
+                           **kw)[1]
     return step
 
 
