@@ -1,29 +1,31 @@
-"""Fault-tolerant driver for the single-host simulated trainer.
+"""The one training loop of the CLI, and the simulated trainer's half.
 
-`run_sim_training` wraps `training.simulated.train_step` with the full
-ISSUE-8 recovery loop while reproducing its math EXACTLY — same key
-discipline (``PRNGKey → split → (k_init, k_run)``, ``fold_in(k_run,
-step)`` per step), same jitted step, same static configs — so a run
-with checkpointing on is bit-identical to one with it off, and a
-killed-and-resumed run replays the identical loss stream:
+`run` drives a step function over a built state.  The trainer hands it
+three things: the state, a step ``(state, batch, step) -> (state,
+metrics)`` that closes over its own key and batch shape, and the key
+its checkpoints must match.  `run_sim_training` builds the
+simulated trainer's state and step; `launch.train.run_distributed`
+builds the shard_map pipeline's.  Around every step the loop owns:
 
 * **checkpoint** — every ``save_every`` steps (plus step 0 at init and
   the final step) the FULL state — params, opt (incl. segment-sharded
   moments), the AQ-SGD message buffers, the ``dp_error`` EF carry —
   is committed via `repro.checkpoint.save_state` together with the
-  PRNG key data, the data-pipeline position, and the recent loss tail;
+  key data, the data-pipeline position, and the recent loss tail;
   ``keep`` rotates old checkpoints out;
 * **resume** — `restore_state` verifies checksums + structure + comm
-  config, the PRNG key data is CHECKED against the live seed (a
+  config, the stored key data is CHECKED against the live one (a
   resume under a different seed fails loudly instead of silently
   forking the trajectory), and the deterministic `data.pipeline`
   stream is replayed by skipping the first ``step`` batches;
 * **inject** — a `repro.comm.faults.FaultPlan` fires at its (step,
-  plane) coordinates: dp faults swap the internal fault-wrapper wire
-  into a replaced static config for exactly that step (clean steps
-  keep the original compiled executable), fw/bw/zbuf faults corrupt
-  the carried state via `inject_sim_state`.  Each fault fires ONCE —
-  the post-recovery replay of the same step runs clean;
+  plane) coordinates through two hooks the trainer passes:
+  ``faulted_step(spec)`` is the step to run instead for a dp fault
+  (the simulated trainer swaps the internal fault-wrapper wire into a
+  replaced static config for exactly that step, so clean steps keep
+  the original compiled executable), and ``inject(state, spec)``
+  corrupts the carried state for fw/bw/zbuf faults.  Each fault fires
+  ONCE — the post-recovery replay of the same step runs clean;
 * **recover** — after every step the loss (always) and the state
   (when a fault plan or checkpointing is active) pass through
   `check_train_state`; a `WireFaultError` reloads the last good
@@ -40,8 +42,9 @@ CLI parity gates compare exact bits, not printed digits.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -83,53 +86,33 @@ def _loss_line(step: int, loss: float) -> str:
             f"[{float(loss).hex()}]")
 
 
-def run_sim_training(mcfg, tcfg, dataset, *, num_steps: int,
-                     batch_size: int, log_every: int = 10,
-                     ckpt_dir: str = "", save_every: int = 0,
-                     keep: int = 3, resume: bool = False,
-                     max_retries: int = 2,
-                     fault_plan: Optional[F.FaultPlan] = None,
-                     kill_at: Optional[int] = None, key=None,
-                     profile: Optional[tracing.StepProfile] = None,
-                     print_fn=print):
-    """Run the simulated trainer with checkpoint/resume, deterministic
-    fault injection, and guarded recovery (module docstring).  Returns
-    ``(state, losses)`` where ``losses`` covers the steps THIS call
-    executed (a resumed call starts at the checkpoint step).
-
-    Math-identical to `training.simulated.train` — checkpointing off
-    and an empty fault plan reproduce its loss stream bit-for-bit."""
-    from repro.training import simulated as sim
-
-    comm = tcfg.comm
+def run(step_fn: Callable, state, dataset, *, comm, key,
+        num_steps: int, batch_size: int, log_every: int = 10,
+        ckpt_dir: str = "", save_every: int = 0, keep: int = 3,
+        resume: bool = False, max_retries: int = 2,
+        fault_plan: Optional[F.FaultPlan] = None,
+        faulted_step: Optional[Callable] = None,
+        inject: Optional[Callable] = None,
+        kill_at: Optional[int] = None,
+        profile: Optional[tracing.StepProfile] = None, print_fn=print):
+    """Run ``step_fn`` from ``state`` with checkpoint/resume, fault
+    injection and guarded recovery (module docstring).  ``comm`` is the
+    run's `CommConfig` (checked on restore, read by the guard); ``key``
+    is the PRNG key whose raw words a checkpoint stores and a resume
+    must match.  Returns ``(state, losses)`` where ``losses`` covers
+    the steps THIS call executed (a resumed call starts at the
+    checkpoint step)."""
     plan = fault_plan or F.FaultPlan()
-    for spec in plan.faults:
-        if spec.plane == "kv":
-            raise ValueError("kv faults target the serving batcher "
-                             "(launch.serve), not the trainer")
-        if spec.plane == "dp" and not comm.dp.bits:
-            raise ValueError(f"fault {spec.text()!r} needs "
-                             f"--dp-grad-bits > 0")
-        if spec.plane in ("fw", "zbuf") and comm.mode != "aqsgd":
-            raise ValueError(f"fault {spec.text()!r} needs "
-                             f"mode='aqsgd' (message buffers)")
-        if spec.plane == "zbuf" and not comm.zbuf.bits:
-            raise ValueError(f"fault {spec.text()!r} needs "
-                             f"--buffer-bits > 0")
-    if (plan or save_every or resume) and not ckpt_dir:
-        if plan or resume:
-            raise ValueError("--fault/--resume need --ckpt-dir")
+    if (plan or resume) and not ckpt_dir:
+        raise ValueError("--fault/--resume need --ckpt-dir")
     if ckpt_dir:
         removed = ckpt.clean_orphans(ckpt_dir)
         if removed:
             print_fn(f"checkpoint: removed {len(removed)} orphaned "
                      f"tmp entr{'y' if len(removed) == 1 else 'ies'}")
 
-    key = key if key is not None else jax.random.PRNGKey(0)
-    k_init, k_run = jax.random.split(key)
-    state = sim.init_train_state(mcfg, tcfg, dataset.num_samples,
-                                 dataset.dc.seq_len, k_init)
-    save_tree = lambda st: {"state": st, "k_run": _key_data(k_run)}
+    key_data = _key_data(key)
+    save_tree = lambda st: {"state": st, "k_run": key_data}
     like = jax.eval_shape(save_tree, state)
 
     def save(step_done: int, tail: list):
@@ -145,8 +128,7 @@ def run_sim_training(mcfg, tcfg, dataset, *, num_steps: int,
     if resume:
         with tracing.span("ckpt.restore"):
             tree, body = ckpt.restore_state(ckpt_dir, like, comm=comm)
-        if not np.array_equal(np.asarray(tree["k_run"]),
-                              _key_data(k_run)):
+        if not np.array_equal(np.asarray(tree["k_run"]), key_data):
             raise ckpt.CheckpointError(
                 "checkpoint PRNG key != this run's seed — resuming "
                 "would silently fork the trajectory")
@@ -174,7 +156,7 @@ def run_sim_training(mcfg, tcfg, dataset, *, num_steps: int,
                     batch = {k: jnp.asarray(v) for k, v in next(it).items()}
                 it_pos += 1
 
-                step_tcfg = tcfg
+                step_call = step_fn
                 post_step = []
                 for spec in plan.at(step):
                     if spec in fired:
@@ -182,7 +164,7 @@ def run_sim_training(mcfg, tcfg, dataset, *, num_steps: int,
                     fired.add(spec)
                     print_fn(f"injecting fault {spec.text()}")
                     if spec.plane == "dp":
-                        step_tcfg = tcfg.with_comm(F.faulted_comm(comm, spec))
+                        step_call = faulted_step(spec)
                     elif spec.plane == "bw":
                         # a corrupt backward hop lands in the params at the
                         # UPDATE — after the forward wrote clean messages —
@@ -191,15 +173,13 @@ def run_sim_training(mcfg, tcfg, dataset, *, num_steps: int,
                         post_step.append(spec)
                     else:
                         with tracing.span("fault", fault=spec.text()):
-                            state = F.inject_sim_state(state, spec, comm)
+                            state = inject(state, spec)
 
                 with tracing.span("dispatch"):
-                    state, metrics = sim.train_step(
-                        state, batch, jax.random.fold_in(k_run, step),
-                        mcfg=mcfg, tcfg=step_tcfg)
+                    state, metrics = step_call(state, batch, step)
                 for spec in post_step:
                     with tracing.span("fault", fault=spec.text()):
-                        state = F.inject_sim_state(state, spec, comm)
+                        state = inject(state, spec)
                 with tracing.span("sync"):
                     loss = float(metrics["loss"])
                 try:
@@ -216,7 +196,7 @@ def run_sim_training(mcfg, tcfg, dataset, *, num_steps: int,
                                                         comm=comm)
                     state, step = tree["state"], int(body["step"])
                     loss_tail = list(body["extra"].get("losses_tail", []))
-                    losses = [x for x in losses][:max(step - start, 0)]
+                    losses = losses[:max(step - start, 0)]
                     print_fn(f"recovered from checkpoint step {step} "
                              f"(retry {retries}/{max_retries})")
                     continue
@@ -241,3 +221,60 @@ def run_sim_training(mcfg, tcfg, dataset, *, num_steps: int,
     if ckpt_dir and save_every and num_steps % save_every != 0:
         save(num_steps, loss_tail)
     return state, losses
+
+
+def run_sim_training(mcfg, tcfg, dataset, *, num_steps: int,
+                     batch_size: int, log_every: int = 10,
+                     ckpt_dir: str = "", save_every: int = 0,
+                     keep: int = 3, resume: bool = False,
+                     max_retries: int = 2,
+                     fault_plan: Optional[F.FaultPlan] = None,
+                     kill_at: Optional[int] = None, key=None,
+                     profile: Optional[tracing.StepProfile] = None,
+                     print_fn=print):
+    """The simulated trainer through `run`.  Returns ``(state,
+    losses)`` as `run` does.
+
+    Math-identical to `training.simulated.train` — the same key
+    discipline (``PRNGKey → split → (k_init, k_run)``, ``fold_in(k_run,
+    step)`` per step), the same jitted step and static configs — so
+    checkpointing off and an empty fault plan reproduce its loss stream
+    bit-for-bit."""
+    from repro.training import simulated as sim
+
+    comm = tcfg.comm
+    plan = fault_plan or F.FaultPlan()
+    for spec in plan.faults:
+        if spec.plane == "kv":
+            raise ValueError("kv faults target the serving batcher "
+                             "(launch.serve), not the trainer")
+        if spec.plane == "dp" and not comm.dp.bits:
+            raise ValueError(f"fault {spec.text()!r} needs "
+                             f"--dp-grad-bits > 0")
+        if spec.plane in ("fw", "zbuf") and comm.mode != "aqsgd":
+            raise ValueError(f"fault {spec.text()!r} needs "
+                             f"mode='aqsgd' (message buffers)")
+        if spec.plane == "zbuf" and not comm.zbuf.bits:
+            raise ValueError(f"fault {spec.text()!r} needs "
+                             f"--buffer-bits > 0")
+
+    key = key if key is not None else jax.random.PRNGKey(0)
+    k_init, k_run = jax.random.split(key)
+    state = sim.init_train_state(mcfg, tcfg, dataset.num_samples,
+                                 dataset.dc.seq_len, k_init)
+
+    def step_with(step_tcfg):
+        return lambda st, batch, step: sim.train_step(
+            st, batch, jax.random.fold_in(k_run, step), mcfg=mcfg,
+            tcfg=step_tcfg)
+
+    return run(
+        step_with(tcfg), state, dataset, comm=comm, key=k_run,
+        num_steps=num_steps,
+        batch_size=batch_size, log_every=log_every, ckpt_dir=ckpt_dir,
+        save_every=save_every, keep=keep, resume=resume,
+        max_retries=max_retries, fault_plan=plan,
+        faulted_step=lambda spec: step_with(dataclasses.replace(
+            tcfg, comm=F.faulted_comm(comm, spec))),
+        inject=lambda st, spec: F.inject_sim_state(st, spec, comm),
+        kill_at=kill_at, profile=profile, print_fn=print_fn)

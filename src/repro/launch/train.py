@@ -113,7 +113,7 @@ def main():
                     help="write a jax.profiler trace of --profile-steps "
                          "under this directory: the device ops beside "
                          "the host spans repro.step > feed, dispatch, "
-                         "sync, guard (single-host trainer only)")
+                         "sync, guard")
     ap.add_argument("--profile-steps", type=_step_range, default=(1, 11),
                     metavar="A:B",
                     help="steps A to B-1 to profile (default 1:11, "
@@ -122,14 +122,19 @@ def main():
                     help="hard-exit (os._exit 17) right after "
                          "printing step N's loss, before any save — "
                          "the kill half of the kill-and-resume parity "
-                         "gate (single-host trainer only)")
+                         "gate")
     args = ap.parse_args()
 
     if args.list_wires:
         print_wires()
         return
+    if args.fault and args.distributed:
+        ap.error("--fault targets the single-host simulated trainer")
+    if (args.resume or args.save_every or args.fault) \
+            and not args.ckpt_dir:
+        ap.error("--resume/--save-every/--fault need --ckpt-dir")
 
-    from repro import env
+    from repro import env, tracing
     from repro.configs.base import get_config
     from repro.data.pipeline import Dataset, DatasetConfig
     from repro.optim.adamw import AdamWConfig
@@ -146,66 +151,51 @@ def main():
     opt = AdamWConfig(lr=args.lr, warmup_steps=max(args.steps // 20, 1),
                       total_steps=args.steps)
 
-    if args.fault and args.distributed:
-        ap.error("--fault targets the single-host simulated trainer")
-    if args.kill_at is not None and args.distributed:
-        ap.error("--kill-at targets the single-host simulated trainer")
-    if args.profile_dir and args.distributed:
-        ap.error("--profile-dir targets the single-host simulated trainer")
-    if (args.resume or args.save_every or args.fault) \
-            and not args.ckpt_dir:
-        ap.error("--resume/--save-every/--fault need --ckpt-dir")
-
-    if not args.distributed:
-        from repro import tracing
-        from repro.comm.faults import FaultPlan
-        from repro.launch import runner
-        from repro.training import simulated as sim
-        tcfg = sim.SimTrainConfig(num_stages=args.stages, comm=comm,
-                                  optimizer=opt,
-                                  dp_workers=args.dp_workers
-                                  if comm.dp.bits else 1)
-        state, losses = runner.run_sim_training(
-            cfg, tcfg, ds, num_steps=args.steps,
-            batch_size=args.batch, log_every=10,
-            ckpt_dir=args.ckpt_dir, save_every=args.save_every,
-            keep=args.keep, resume=args.resume,
-            max_retries=args.max_retries,
-            fault_plan=FaultPlan.parse(args.fault),
-            kill_at=args.kill_at,
-            profile=tracing.StepProfile(args.profile_dir,
-                                        *args.profile_steps)
-            if args.profile_dir else None)
-        print(f"final loss {np.mean(losses[-5:]):.4f}")
-        if args.checkpoint:
-            ckpt.save(args.checkpoint, state["params"])
-            print("saved", args.checkpoint)
+    profile = tracing.StepProfile(args.profile_dir, *args.profile_steps) \
+        if args.profile_dir else None
+    loop = dict(ckpt_dir=args.ckpt_dir, save_every=args.save_every,
+                keep=args.keep, resume=args.resume,
+                max_retries=args.max_retries, kill_at=args.kill_at,
+                profile=profile)
+    if args.distributed:
+        run_distributed(cfg, comm, ds, opt, stages=args.stages,
+                        data_par=args.data_par,
+                        microbatches=args.microbatches, batch=args.batch,
+                        seq=args.seq, samples=args.samples,
+                        steps=args.steps,
+                        warmup_epochs=args.warmup_epochs, **loop)
         return
 
-    run_distributed(cfg, comm, ds, opt, stages=args.stages,
-                    data_par=args.data_par,
-                    microbatches=args.microbatches, batch=args.batch,
-                    seq=args.seq, samples=args.samples,
-                    steps=args.steps, warmup_epochs=args.warmup_epochs,
-                    ckpt_dir=args.ckpt_dir, save_every=args.save_every,
-                    keep=args.keep, resume=args.resume)
+    from repro.comm.faults import FaultPlan
+    from repro.launch import runner
+    from repro.training import simulated as sim
+    tcfg = sim.SimTrainConfig(num_stages=args.stages, comm=comm,
+                              optimizer=opt,
+                              dp_workers=args.dp_workers
+                              if comm.dp.bits else 1)
+    state, losses = runner.run_sim_training(
+        cfg, tcfg, ds, num_steps=args.steps, batch_size=args.batch,
+        log_every=10, fault_plan=FaultPlan.parse(args.fault), **loop)
+    print(f"final loss {np.mean(losses[-5:]):.4f}")
+    if args.checkpoint:
+        ckpt.save(args.checkpoint, state["params"])
+        print("saved", args.checkpoint)
 
 
 def run_distributed(cfg, comm, ds, opt, *, stages: int, data_par: int,
                     microbatches: int, batch: int, seq: int, samples: int,
                     steps: int, warmup_epochs: int = 1, ckpt_dir: str = "",
                     save_every: int = 0, keep: int = 3,
-                    resume: bool = False, log_every: int = 10,
+                    resume: bool = False, max_retries: int = 2,
+                    kill_at=None, profile=None, log_every: int = 10,
                     key=None, print_fn=print):
     """The shard_map GPipe pipeline on a (data_par, stages) mesh of the
-    visible devices — the ``--distributed`` path.  Returns ``(state,
+    visible devices — the ``--distributed`` path, through the same loop
+    as the simulated trainer (`launch.runner.run`).  Returns ``(state,
     losses)``, one loss per step this call ran."""
     import jax
-    import jax.numpy as jnp
-    from repro.checkpoint import checkpoint as ckpt
+    from repro.launch import runner
     from repro.launch.mesh import make_debug_mesh
-    from repro.models import model as Mo
-    from repro.optim import adamw
     from repro.training import pipeline as PL
 
     mesh = make_debug_mesh(data_par, stages)
@@ -216,71 +206,33 @@ def run_distributed(cfg, comm, ds, opt, *, stages: int, data_par: int,
         steps_w[warm], meta = PL.make_train_step(
             cfg, pcfg, mesh, opt, global_batch=batch, seq_len=seq,
             buffer_samples=samples // data_par)
-
-    def init_state():
-        params = PL.to_pipeline_params(
-            cfg, Mo.init_params(cfg, key if key is not None
-                                else jax.random.PRNGKey(0)), stages)
-        if comm.dp.bits and comm.dp_wire_spec.sharded:
-            opt_state = PL.init_sharded_opt(pcfg, params, data_par)
-        else:
-            opt_state = adamw.init_opt_state(params)
-        state = {"params": params, "opt": opt_state}
-        if comm.dp.bits:
-            state["dp_error"] = PL.init_dp_error(pcfg, params, data_par)
-        if comm.mode == "aqsgd":
-            n_loc = samples // data_par
-            structs = PL.buffer_structs(pcfg, stages, data_par * n_loc,
-                                        seq, cfg.d_model)
-            zeros = lambda s: jnp.zeros(s.shape, s.dtype)
-            state["m_out"] = jax.tree.map(zeros, structs)
-            state["m_in"] = jax.tree.map(zeros, structs)
-        return state
-
+    key = key if key is not None else jax.random.PRNGKey(0)
     # built in place on the step's shardings: each device holds only its
     # own stage's slice, never the whole model
-    state = jax.jit(init_state, out_shardings=meta["state_specs"])()
-
-    start = 0
-    if ckpt_dir:
-        removed = ckpt.clean_orphans(ckpt_dir)
-        if removed:
-            print_fn(f"checkpoint: removed {len(removed)} orphaned tmp "
-                     f"entries")
-    if resume:
-        state, body = ckpt.restore_state(ckpt_dir,
-                                         jax.eval_shape(lambda: state),
-                                         comm=comm)
-        start = int(body["step"])
-        print_fn(f"resumed from step {start}")
+    state = jax.jit(lambda: PL.init_train_state(cfg, pcfg, meta, mesh, key),
+                    out_shardings=meta["state_specs"])()
 
     m = microbatches
-    steps_per_epoch = max(samples // batch, 1)
+    warm_steps = max(samples // batch, 1) * warmup_epochs \
+        if comm.mode == "aqsgd" else 0
     step_key = jax.random.PRNGKey(1)
-    ds.reset()          # the batch stream is a function of the config
-    batches = ds.batches(batch, steps)
-    for _ in range(start):
-        next(batches)   # the data stream is deterministic: replay by
-                        # skipping to the checkpointed position
-    losses = []
-    for step_i, b in enumerate(batches, start=start):
-        b = {k: jnp.asarray(v).reshape(m, batch // m, *v.shape[1:])
-             for k, v in b.items()}
-        warm = comm.mode == "aqsgd" \
-            and step_i < steps_per_epoch * warmup_epochs
-        state, metrics = steps_w[warm](state, b,
-                                       jax.random.fold_in(step_key, step_i))
-        loss = float(metrics["loss"])
-        losses.append(loss)
-        if log_every and step_i % log_every == 0:
-            print_fn(f"step {step_i:5d} loss {loss:.4f} [{loss.hex()}]")
-        done = step_i + 1
-        if ckpt_dir and save_every and done % save_every == 0:
-            ckpt.save_state(ckpt_dir, state, step=done, comm=comm,
-                            extra={"data_position": done}, keep=keep)
+
+    def step(state, b, i):
+        b = {k: v.reshape(m, batch // m, *v.shape[1:]) for k, v in b.items()}
+        return steps_w[i < warm_steps](state, b,
+                                       jax.random.fold_in(step_key, i))
+
+    # a restored state is host arrays: the step's in_shardings place it
+    state, losses = runner.run(
+        step, state, ds, comm=comm, key=step_key,
+        num_steps=steps, batch_size=batch, log_every=log_every,
+        ckpt_dir=ckpt_dir, save_every=save_every, keep=keep,
+        resume=resume, max_retries=max_retries, kill_at=kill_at,
+        profile=profile, print_fn=print_fn)
     if losses:
         print_fn(f"final loss {losses[-1]:.4f}")
     return state, losses
+
 
 if __name__ == "__main__":
     main()

@@ -71,7 +71,6 @@ also under ``lax.cond``.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 from dataclasses import InitVar, dataclass
 from typing import Any, Optional
@@ -109,8 +108,7 @@ class PipelineConfig:
     kept only so passing one raises a loud migration error pointing at
     ``comm=`` instead of an opaque TypeError.  Read the old values off
     ``comm`` directly (``cfg.comm.dp.bits``, ``cfg.comm.activation``,
-    ...); ``dataclasses.replace(cfg, comm=new)`` and ``with_comm``
-    both swap comm."""
+    ...); ``dataclasses.replace(cfg, comm=new)`` swaps comm."""
     microbatches: int = 16
     comm: Optional[CommConfig] = None
     warmup: bool = False            # warm-up epoch: uncompressed, fills m
@@ -137,12 +135,6 @@ class PipelineConfig:
              "dp_grad_group": dp_grad_group, "dp_wire": dp_wire})
         if self.comm is None:
             object.__setattr__(self, "comm", CommConfig())
-
-    def with_comm(self, comm: CommConfig) -> "PipelineConfig":
-        """Copy of this config with ``comm`` swapped (equivalent to
-        ``dataclasses.replace(self, comm=comm)``; kept because it
-        predates the removal of the legacy mirror kwargs)."""
-        return dataclasses.replace(self, comm=comm)
 
 
 # ---------------------------------------------------------------------------
@@ -1120,49 +1112,46 @@ def make_train_step(cfg: ModelConfig, pcfg: PipelineConfig, mesh,
     return step, meta
 
 
+def init_train_state(cfg: ModelConfig, pcfg: PipelineConfig, meta, mesh,
+                     key, *, opt_state_bits: int = 0) -> dict:
+    """The pipeline train state `make_train_step`'s step takes: params
+    in pipeline layout (``cfg.dtype``), AdamW moments (per leaf, or
+    segment-sharded under a sharded DP wire), the ``dp_error`` EF stack
+    and the ``m_out``/``m_in`` message buffers.  The one place that
+    knows the layout: `make_state_structs` is its ``eval_shape``, and
+    `launch.train.run_distributed` jits it onto ``meta["state_specs"]``."""
+    K = mesh.shape["model"]
+    D = int(np.prod([mesh.shape[a] for a in data_axes(mesh)]))
+    comm = pcfg.comm
+    params = to_pipeline_params(cfg, Mo.init_params(cfg, key), K)
+    if comm.dp.bits and comm.dp_wire_spec.sharded:
+        opt = init_sharded_opt(pcfg, params, D)
+    else:
+        opt = adamw.init_opt_state(params, opt_state_bits)
+    dt = cfg.jax_dtype
+    state = {"params": jax.tree.map(lambda a: a.astype(dt), params),
+             "opt": opt}
+    if comm.dp.bits:
+        state["dp_error"] = init_dp_error(pcfg, params, D)
+    if comm.mode == "aqsgd":
+        structs = buffer_structs(pcfg, K, D * meta["buffer_samples"],
+                                 meta["trunk_seq"], cfg.d_model)
+        zeros = lambda s: jnp.zeros(s.shape, s.dtype)
+        state["m_out"] = jax.tree.map(zeros, structs)
+        state["m_in"] = jax.tree.map(zeros, structs)
+    return state
+
+
 def make_state_structs(cfg: ModelConfig, pcfg: PipelineConfig, meta,
                        mesh, *, global_batch: int, seq_len: int,
                        opt_state_bits: int = 0):
-    """ShapeDtypeStructs for the dry-run (no allocation)."""
+    """ShapeDtypeStructs for the dry-run (no allocation): the state is
+    `init_train_state`'s ``eval_shape``."""
     dt = cfg.jax_dtype
-    params = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, dt), meta["params_shape"])
-    daxes = data_axes(mesh)
-    D = int(np.prod([mesh.shape[a] for a in daxes]))
-    comm = pcfg.comm
-    if comm.dp.bits and comm.dp_wire_spec.sharded:
-        # segment-partitioned bucket moments (one segment per DP rank)
-        opt = jax.eval_shape(lambda p: init_sharded_opt(pcfg, p, D),
-                             meta["params_shape"])
-    else:
-        if opt_state_bits:
-            def qstruct(s):
-                return {"codes": jax.ShapeDtypeStruct(s.shape, jnp.uint8),
-                        "scale": jax.ShapeDtypeStruct(
-                            (*s.shape[:-1], 1), jnp.float32)}
-            moments = jax.tree.map(qstruct, params)
-        else:
-            moments = jax.tree.map(
-                lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32),
-                params)
-        opt = {"mu": moments, "nu": moments,
-               "step": jax.ShapeDtypeStruct((), jnp.int32)}
-    state = {"params": params, "opt": opt}
-    if comm.dp.bits:
-        # derived by eval_shape of the ONE init function so the struct
-        # cannot drift from the layout `make_train_step` traces against
-        # (tests/test_grad_compress.py pins this on the worker meshes)
-        state["dp_error"] = jax.eval_shape(
-            lambda p: init_dp_error(pcfg, p, D), meta["params_shape"])
-    if comm.mode == "aqsgd":
-        K = mesh.shape["model"]
-        daxes = data_axes(mesh)
-        D = int(np.prod([mesh.shape[a] for a in daxes]))
-        n_loc = meta["buffer_samples"]
-        state["m_out"] = buffer_structs(pcfg, K, D * n_loc,
-                                        meta["trunk_seq"], cfg.d_model)
-        state["m_in"] = buffer_structs(pcfg, K, D * n_loc,
-                                       meta["trunk_seq"], cfg.d_model)
+    state = jax.eval_shape(
+        functools.partial(init_train_state, cfg, pcfg, meta, mesh,
+                          opt_state_bits=opt_state_bits),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
     n_text = seq_len - (cfg.num_patches or 0)
     m = meta["m"]
     bmb = global_batch // m

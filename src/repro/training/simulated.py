@@ -95,13 +95,6 @@ class SimTrainConfig:
         if self.comm is None:
             object.__setattr__(self, "comm", CommConfig())
 
-    def with_comm(self, comm: CommConfig) -> "SimTrainConfig":
-        """Copy with ``comm`` swapped (equivalent to
-        ``dataclasses.replace``; kept because it predates the removal
-        of the legacy mirror kwargs)."""
-        import dataclasses as _dc
-        return _dc.replace(self, comm=comm)
-
 
 def init_train_state(mcfg: ModelConfig, tcfg: SimTrainConfig,
                      num_samples: int, seq_len: int, key) -> dict:

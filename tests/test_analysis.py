@@ -27,7 +27,7 @@ import pytest
 
 from repro.analysis import (get_rule, iter_rules, lint_text, run_lint,
                             run_rule)
-from test_distributed import run_worker
+from workerproc import run_worker
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

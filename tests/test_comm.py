@@ -304,13 +304,14 @@ def test_pipeline_config_legacy_kwargs_removed():
                  "dp_grad_group", "dp_wire"):
         assert not isinstance(getattr(type(new), name, None), property)
         assert getattr(new, name, None) is None
-    # replace()/with_comm both swap comm cleanly now that the InitVar
-    # defaults are all None (nothing re-raises)
+    # replace() swaps comm cleanly now that the InitVar defaults are
+    # all None (nothing re-raises)
     rep = dataclasses.replace(new, warmup=True)
     assert rep.comm == new.comm and rep.warmup
-    swapped = new.with_comm(
-        CommConfig(dp=PlaneConfig(bits=4, wire="psum")))
+    swapped = dataclasses.replace(
+        new, comm=CommConfig(dp=PlaneConfig(bits=4, wire="psum")))
     assert swapped.comm.dp.wire == "psum" and swapped.comm.zbuf.bits == 0
+    assert not hasattr(new, "with_comm")
     assert dataclasses.replace(
         new, comm=swapped.comm).comm.dp.wire == "psum"
     # the sanctioned migration path reproduces the old kwarg semantics
@@ -344,9 +345,11 @@ def test_sim_config_legacy_kwargs_removed():
             dp_grad_bits=4, dp_wire="ring-sharded"),
         dp_workers=2)
     assert via_legacy.comm == new.comm
-    swapped = new.with_comm(CommConfig(dp=PlaneConfig(bits=4)))
+    swapped = dataclasses.replace(new,
+                                  comm=CommConfig(dp=PlaneConfig(bits=4)))
     assert swapped.comm.dp_wire_spec.sharded is False
     assert swapped.comm.dp.bits == 4 and swapped.dp_workers == 2
+    assert not hasattr(new, "with_comm")
 
 
 def test_fp16_wire_sim_trains():

@@ -15,7 +15,6 @@ bit-parity per plane, and the real CLI `--kill-at` (exit 17) /
 `--resume` path in subprocesses.
 """
 import os
-import re
 import subprocess
 import sys
 
@@ -26,8 +25,7 @@ import pytest
 
 from repro.comm import CommConfig, faults as F, wires as W
 from repro.data.pipeline import Dataset, DatasetConfig
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from workerproc import ROOT, loss_lines
 
 
 # ---------------------------------------------------------------------------
@@ -388,11 +386,6 @@ def _cli(extra, timeout=900):
         capture_output=True, text=True, timeout=timeout, env=env)
 
 
-def _loss_lines(stdout):
-    return [ln for ln in stdout.splitlines()
-            if re.match(r"(step\s+\d+ loss|final loss)", ln)]
-
-
 @pytest.mark.slow
 def test_cli_kill_resume_bit_parity(tmp_path):
     base = _cli([])
@@ -409,8 +402,8 @@ def test_cli_kill_resume_bit_parity(tmp_path):
     assert "resumed from step 6" in resumed.stdout
     # step-10 line carries the loss bits (float.hex); final loss is
     # the mean of the last 5 — both must match the uninterrupted run
-    base_lines = _loss_lines(base.stdout)
-    res_lines = _loss_lines(resumed.stdout)
+    base_lines = loss_lines(base.stdout)
+    res_lines = loss_lines(resumed.stdout)
     assert res_lines == [ln for ln in base_lines
                          if not ln.startswith("step     0 ")]
 
@@ -425,4 +418,4 @@ def test_cli_fault_recovers(tmp_path):
     assert "guard tripped" in hit.stdout
     assert "plane=dp" in hit.stdout and "step=5" in hit.stdout
     assert "recovered from checkpoint step 3" in hit.stdout
-    assert _loss_lines(hit.stdout) == _loss_lines(base.stdout)
+    assert loss_lines(hit.stdout) == loss_lines(base.stdout)

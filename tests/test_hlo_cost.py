@@ -13,7 +13,7 @@ import pytest
 from repro.core import quantization as Q
 from repro.launch.hlo_cost import hlo_cost
 from repro.launch.mesh import make_mesh_auto, shard_map
-from test_distributed import run_worker
+from workerproc import run_worker
 
 
 def _compiled(f, *specs):
